@@ -3,7 +3,7 @@ in test_lint_device_sync.py).
 
 Serving packages (server/, filer/, s3/, mount/) must never touch the
 accelerator directly: a bare ``jax.device_get``/``.block_until_ready``
-stalls a request thread behind the (possibly relayed) link for the
+stalls a request thread behind the host<->device link for the
 whole transfer, and an argless ``device_put(x)`` uploads to an
 UNCOMMITTED default device — XLA is then free to re-copy the array per
 executable. All device traffic belongs in the staged pipeline

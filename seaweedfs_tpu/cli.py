@@ -54,7 +54,8 @@ def _add_commit_flags(p) -> None:
              "before -commit.maxDelay elapses (default 4MiB)")
 
 
-def main(argv: list[str] | None = None) -> int:
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
+    """The command line, parsed: every subcommand and its flags."""
     parser = argparse.ArgumentParser(
         prog="seaweedfs-tpu",
         description="TPU-native distributed object store")
@@ -811,6 +812,22 @@ def main(argv: list[str] | None = None) -> int:
 
     args = parser.parse_args(argv)
     args._subcommands = list(sub.choices)
+    return args
+
+
+# the subcommands whose process may build a device codec, and so
+# compile: the volume server and the all-in-one server
+_CODEC_COMMANDS = ("volume", "server")
+
+
+def configure(args: argparse.Namespace) -> None:
+    """Process-wide setup from the parsed flags (logging, tracing,
+    retry, QoS, faults, telemetry; the compile cache for the commands
+    that run a codec) — everything `main` does before dispatching."""
+    if args.cmd in _CODEC_COMMANDS:
+        from .ops import device
+
+        device.setup_compile_cache()
     if args.verbosity or args.vmodule:
         from .utils import glog
 
@@ -865,6 +882,11 @@ def main(argv: list[str] | None = None) -> int:
     _sketch.configure(enabled=args.telemetry_enabled,
                       alpha=args.telemetry_alpha,
                       window=args.telemetry_window)
+
+
+def main(argv: list[str] | None = None) -> int:
+    args = parse_args(argv)
+    configure(args)
     if args.memprofile:
         import tracemalloc
 
@@ -1517,7 +1539,17 @@ def _run_s3(args) -> int:
 
 
 def _run_server(args) -> int:
-    from .rpc.http import ServerThread, run_apps_forever
+    from .rpc.http import run_apps_forever
+
+    run_apps_forever(start_server(args))
+    return 0
+
+
+def start_server(args: argparse.Namespace) -> list:
+    """Start the all-in-one node (`server`): master + volume server,
+    plus filer/S3 when asked, each on its own ServerThread. Returns the
+    threads; the caller serves them and stops them."""
+    from .rpc.http import ServerThread
     from .server.master_server import MasterServer
     from .server.volume_server import VolumeServer
     from .storage.store import Store
@@ -1632,8 +1664,7 @@ def _run_server(args) -> int:
                                   port=args.s3_port).start()
                 threads.append(st)
                 print(f"s3 gateway listening on {st.url}")
-    run_apps_forever(threads)
-    return 0
+    return threads
 
 
 def _run_benchmark(args) -> int:

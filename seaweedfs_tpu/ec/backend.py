@@ -65,6 +65,10 @@ class CodecBackend(Protocol):
         ...
 
 
+# the codecs that run on the accelerator (and so need the process
+# that owns it)
+DEVICE_BACKENDS = ("pallas", "jax", "mesh")
+
 _factories: dict[str, Callable[[], CodecBackend]] = {}
 _instances: dict[str, CodecBackend] = {}
 
@@ -118,8 +122,9 @@ def _register_builtins() -> None:
     register("numpy", codec_numpy.NumpyCodec)
 
     def _jax_factory():
-        from ..ops import codec_jax
+        from ..ops import codec_jax, device
 
+        device.require_accelerator("jax")
         return codec_jax.JaxCodec()
 
     register("jax", _jax_factory)
@@ -132,15 +137,17 @@ def _register_builtins() -> None:
     register("native", _native_factory)
 
     def _pallas_factory():
-        from ..ops import codec_pallas
+        from ..ops import codec_pallas, device
 
+        device.require_accelerator("pallas")
         return codec_pallas.PallasCodec()
 
     register("pallas", _pallas_factory)
 
     def _mesh_factory():
-        from ..ops import codec_mesh
+        from ..ops import codec_mesh, device
 
+        device.require_accelerator("mesh")
         return codec_mesh.MeshCodec()
 
     register("mesh", _mesh_factory)
@@ -324,11 +331,15 @@ def pipeline_depth_for(nbytes: int, code: str = "") -> int:
 def choose_auto_backend() -> str:
     """Process-wide codec choice for bulk work, from measurement, not
     faith: the size x depth sweep of the real pipelined feed
-    (ec/probe.py) interpolated at the bulk request size. A TPU behind
-    fast DMA beats the CPU codec by orders of magnitude; the same TPU
-    behind a slow tunnel LOSES to the AVX2 library no matter how fast
-    its MXU is — and only the measured e2e curve can tell the cases
-    apart. Override with env SEAWEEDFS_TPU_EC_BACKEND.
+    (ec/probe.py) interpolated at the bulk request size. However fast
+    the MXU, the device only wins where its host<->device feed
+    beats the AVX2 library end to end, and only the measured e2e
+    curve can tell. Override with env SEAWEEDFS_TPU_EC_BACKEND.
+
+    A probe that raises is a device error, not a routing decision: the
+    process falls back to the CPU codec, but the error is logged at
+    warning level, counted in ec_device_errors_total and kept in the
+    /debug/ec summary under `device_error`.
 
     The decision is cached per process; the sweep result is cached on
     disk (TTL + host fingerprint), so across serving processes the
@@ -348,9 +359,10 @@ def choose_auto_backend() -> str:
         curve = probe.get_curve()
         choice = _decide(curve, _ROUTER_BULK_BYTES)
         summary = probe.summary(curve)
-    except Exception as e:  # pragma: no cover - probe must never fatal
+    except Exception as e:  # the probe must never take the server down
         choice = _probe_cpu_backend()
-        summary = {"error": repr(e)}
+        summary = {"device_error": repr(e)}
+        record_device_error("router", e)
     _auto_choice = choice
     summary["chosen"] = choice
     _auto_probe = summary
@@ -362,6 +374,17 @@ def choose_auto_backend() -> str:
     except Exception:  # pragma: no cover
         pass
     return choice
+
+
+def record_device_error(stage: str, err) -> None:
+    """A device that errored: warning log plus
+    ec_device_errors_total{stage}. Every path that falls back to the
+    CPU because the device failed (rather than because the measured
+    curve said so) goes through here."""
+    metrics.counter_add("ec_device_errors_total", 1, {"stage": stage})
+    from ..utils import glog
+
+    glog.warning("ec device error (%s): %s", stage, err)
 
 
 def router_buckets(curve: dict) -> list[dict]:
@@ -416,12 +439,19 @@ def probe_snapshot() -> dict:
     curve, where it came from (process sweep vs disk cache), how stale
     it is, and the per-size-bucket decision. Never triggers a sweep —
     an unprobed process says so instead of stalling the debug handler
-    for the probe's budget."""
+    for the probe's budget — and never initialises a JAX backend: a
+    process that holds no device (master, filer, gateways) reports
+    "no device in this process" instead of taking the chip from the
+    volume server."""
     import time as _t
 
+    from ..ops import device
     from . import probe
 
+    touch = device.backends_initialized()
     snap: dict = {
+        "device": (probe.process_device() if touch
+                   else "no device in this process"),
         "env_override": os.environ.get(_AUTO_ENV, "").strip() or None,
         "process_choice": _auto_choice,
         "cpu_backend": _probe_cpu_backend(),
@@ -431,19 +461,21 @@ def probe_snapshot() -> dict:
         "default_code": default_code_spec() or "10.4",
         "codes": code_table(),
     }
+    if _auto_probe and _auto_probe.get("device_error"):
+        snap["device_error"] = _auto_probe["device_error"]
     # per-code router state: each known code's measured curve (when
     # one exists — peek never sweeps) and the bucket choices it yields
     per_code: dict[str, dict] = {}
     for spec in KNOWN_CODES:
         ckey = _curve_code(spec)
-        ccurve = probe.peek(code=ckey)
+        ccurve = probe.peek(code=ckey, touch=touch)
         if ccurve is None:
             per_code[spec] = {"state": "unprobed"}
         else:
             per_code[spec] = {"state": "measured",
                               "buckets": router_buckets(ccurve)}
     snap["code_buckets"] = per_code
-    curve = probe.peek()
+    curve = probe.peek(touch=touch)
     if curve is None:
         snap["probe"] = {"state": "unprobed"}
         return snap
@@ -455,6 +487,8 @@ def probe_snapshot() -> dict:
         "summary": probe.summary(curve),
         "rows": curve.get("rows", []),
     }
+    if curve.get("device_error"):
+        snap["device_error"] = curve["device_error"]
     snap["buckets"] = router_buckets(curve)
     return snap
 

@@ -239,8 +239,8 @@ def _region_blocks(dat: np.ndarray, start: int, n_rows: int,
     write order.
 
     wide=True packs many rows per dispatch via a transpose gather —
-    right for device codecs, whose per-dispatch cost (relay RTT, jit
-    launch) dwarfs the strided copy. wide=False walks one stripe row
+    right for device codecs, whose per-dispatch cost (transfer setup,
+    kernel launch) dwarfs the strided copy. wide=False walks one stripe row
     at a time: a full row is a CONTIGUOUS window of the .dat, so the
     codec input is a zero-copy reshape view — no gather at all except
     the zero-padded tail row. Right for CPU codecs, where the
@@ -301,7 +301,7 @@ def _encode_region(rs: ReedSolomon, dat: np.ndarray, start: int, n_rows: int,
     k = rs.k
     # CPU codecs take narrow zero-copy row views (the transpose gather
     # was their residual overhead); device codecs get wide packed
-    # dispatches that amortize relay/launch latency. `auto` must be
+    # dispatches that amortize transfer/launch latency. `auto` must be
     # RESOLVED first or the production default would silently keep the
     # wide gather on CPU machines — the exact overhead this removes.
     backend_name = getattr(rs.backend, "name", "")

@@ -96,6 +96,16 @@ def _device() -> tuple[str, str, int] | None:
             len(jax.devices()))
 
 
+def process_device() -> dict | None:
+    """The accelerator this process holds, for /debug/ec: None when it
+    runs on the CPU. Only for a process whose JAX backend is already
+    up (ops.device.backends_initialized()): asking for devices
+    initialises one."""
+    dev = _device()
+    return ({"platform": dev[0], "kind": dev[1], "count": dev[2]}
+            if dev else None)
+
+
 def _visible_device_count() -> int | None:
     """Total visible jax devices on ANY platform (None when jax is
     absent). The accelerator-only `_device()` is not enough for the
@@ -315,8 +325,7 @@ def run_sweep(sizes=SWEEP_SIZES, depths=SWEEP_DEPTHS,
         except KeyError:
             continue
     if codec is None:
-        curve["device_error"] = "no device codec backend importable"
-        return curve
+        return _device_failed(curve, "no device codec backend importable")
 
     try:
         # spin up the path (first device_put, executor machinery)
@@ -324,8 +333,7 @@ def run_sweep(sizes=SWEEP_SIZES, depths=SWEEP_DEPTHS,
         # warm pass below so no (size, depth) row is billed a compile
         _measure_e2e_row(codec, coef, 1 << 18, 1, n_blocks=2, k=k, m=m)
     except Exception as e:
-        curve["device_error"] = repr(e)
-        return curve
+        return _device_failed(curve, repr(e))
 
     last_rate: float | None = None
 
@@ -396,6 +404,25 @@ def run_sweep(sizes=SWEEP_SIZES, depths=SWEEP_DEPTHS,
         last_rate = _sweep_mesh_rows(curve, sizes, depths, remaining,
                                      last_rate, coef=coef, k=k, m=m)
     curve["sweep_seconds"] = round(_time.perf_counter() - t_start, 2)
+    errors = [r["error"] for key in ("rows", "mesh_rows")
+              for r in curve.get(key, []) if "error" in r]
+    if curve.get("mesh_error"):
+        errors.append(curve["mesh_error"])
+    if errors:
+        return _device_failed(curve, errors[0])
+    return curve
+
+
+def _device_failed(curve: dict, err: str) -> dict:
+    """Record a device that errored during the sweep: `device_error`
+    in the curve (and so in /debug/ec), a warning log and
+    ec_device_errors_total{stage="probe"}. The router still routes on
+    whatever rows did measure, but the failure stays an error on
+    record, not a routing decision."""
+    from . import backend as ecb
+
+    curve["device_error"] = err
+    ecb.record_device_error("probe", err)
     return curve
 
 
@@ -517,7 +544,10 @@ def get_curve(refresh: bool = False, code: str = "") -> dict:
     curve = None if refresh else load_cached(code=code)
     if curve is None:
         curve = run_sweep(code=code)
-        if curve.get("device") is not None:
+        # a device that errored is re-probed by the next process, not
+        # remembered for a TTL as if the CPU had won
+        if curve.get("device") is not None and \
+                not curve.get("device_error"):
             save_cache(curve, cache_path(code))
         curve["source"] = "fresh"
     else:
@@ -526,13 +556,19 @@ def get_curve(refresh: bool = False, code: str = "") -> dict:
     return curve
 
 
-def peek(code: str = "") -> dict | None:
+def peek(code: str = "", touch: bool = True) -> dict | None:
     """The curve if this process already has one (memo or a valid disk
     cache) — never sweeps. Debug surfaces use this so a GET can't
-    stall behind the probe budget."""
+    stall behind the probe budget; they pass touch=False, and then a
+    process without a JAX backend does not read the disk cache, whose
+    fingerprint check would initialise one."""
+    from ..ops import device
+
     memo = _curves.get(code)
     if memo is not None:
         return memo
+    if not touch and not device.backends_initialized():
+        return None
     curve = load_cached(code=code)
     if curve is not None:
         curve["source"] = "cache"
@@ -630,6 +666,7 @@ def summary(curve: dict) -> dict:
             for s, r, d in best_by_size(curve)},
         "skipped_rows": sum(1 for r in curve.get("rows", [])
                             if r.get("skipped")),
+        "device_error": curve.get("device_error"),
         "measured_at": curve.get("measured_at"),
         "source": curve.get("source"),
     }

@@ -288,12 +288,8 @@ def sharded_rebuild(mesh, k: int = 10, m: int = 4,
     rebuilt bytes, column-sharded. shards input: (k, n) uint8 with k
     divisible by the mesh size; n divisible by 8*mesh size.
     """
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
-
-    try:
-        from jax import shard_map  # jax >= 0.8
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
 
     if present is None or missing is None:
         missing = list(range(m))

@@ -57,13 +57,10 @@ def _load_locked() -> ctypes.CDLL:
     lib.crc32c_batch.restype = None
     lib.native_simd_level.argtypes = []
     lib.native_simd_level.restype = ctypes.c_int
-    try:
-        lib.gf256_scheduled_matmul.argtypes = [
-            ctypes.POINTER(ctypes.c_int32), u8p, ctypes.c_int,
-            ctypes.c_int64, u8p]
-        lib.gf256_scheduled_matmul.restype = None
-    except AttributeError:  # stale prebuilt .so without the kernel
-        pass
+    lib.gf256_scheduled_matmul.argtypes = [
+        ctypes.POINTER(ctypes.c_int32), u8p, ctypes.c_int,
+        ctypes.c_int64, u8p]
+    lib.gf256_scheduled_matmul.restype = None
     i64p = ctypes.POINTER(ctypes.c_int64)
     lib.dat_scan.argtypes = [
         u8p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int,
@@ -95,12 +92,6 @@ def coded_matmul(coef: np.ndarray, shards: np.ndarray) -> np.ndarray:
     lib.gf256_coded_matmul(_u8p(coef), m, k, _u8p(shards),
                            ctypes.c_int64(n), _u8p(out))
     return out
-
-
-def has_scheduled() -> bool:
-    """Whether the loaded library carries the scheduled XOR kernel
-    (False only for a stale prebuilt .so with no compiler to refresh)."""
-    return hasattr(load(), "gf256_scheduled_matmul")
 
 
 def scheduled_matmul(prog: np.ndarray, shards: np.ndarray,

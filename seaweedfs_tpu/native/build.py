@@ -5,6 +5,12 @@ Run directly (`python seaweedfs_tpu/native/build.py`) or let
 seaweedfs_tpu.native build lazily on first import. No pybind11 — the
 ABI is a C `extern "C"` surface consumed via ctypes.
 
+A built library is used only when it was built here from exactly the
+committed source with exactly these flags: each .so has a sidecar
+``.key`` holding a hash of source + command line + this CPU, and any
+mismatch (a new source, other flags, a .so copied in from another
+machine) rebuilds it. ``-march=native`` makes the CPU part of the key.
+
 Sanitizer builds: ``SEAWEEDFS_TPU_DP_SANITIZE={asan,tsan}`` selects an
 instrumented data-plane build. Each mode caches its own .so
 (libseaweed_dataplane.asan.so / .tsan.so) so switching modes never
@@ -13,7 +19,9 @@ races the plain library, and instrumented builds drop -O3/-march for
 """
 from __future__ import annotations
 
+import hashlib
 import os
+import platform
 import subprocess
 import sys
 
@@ -50,21 +58,60 @@ def dp_lib_path(mode: str | None = None) -> str:
     return f"{base}.{mode}{ext}"
 
 
+def _cpu_id() -> str:
+    """This kind of CPU: the architecture plus the feature flags that
+    -march=native compiles for."""
+    flags = ""
+    try:
+        with open("/proc/cpuinfo", encoding="utf-8") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    flags = line.split(":", 1)[1].strip()
+                    break
+    except OSError:
+        pass
+    return f"{platform.machine()} {flags}"
+
+
+def build_key(src: str, flags: list[str]) -> str:
+    """Hash of what a built library depends on: the source bytes, the
+    compiler flags and this CPU."""
+    h = hashlib.sha256()
+    with open(src, "rb") as f:
+        h.update(f.read())
+    h.update("\0".join(flags).encode())
+    h.update(_cpu_id().encode())
+    return h.hexdigest()
+
+
+def _read_key(lib: str) -> str:
+    try:
+        with open(lib + ".key", encoding="utf-8") as f:
+            return f.read().strip()
+    except OSError:
+        return ""
+
+
 def _compile(src: str, lib: str, verbose: bool,
              extra: list[str] | None = None,
              opt: list[str] | None = None) -> str:
-    if os.path.exists(lib) and \
-            os.path.getmtime(lib) >= os.path.getmtime(src):
+    flags = (opt or ["-O3", "-march=native"]) + \
+        ["-shared", "-fPIC", "-std=c++17"] + (extra or [])
+    key = build_key(src, flags)
+    if os.path.exists(lib) and _read_key(lib) == key:
         return lib
     # compile to a temp name + rename so a concurrent process never
-    # dlopens a half-written library
+    # dlopens a half-written library; the key lands after the library,
+    # so a reader that sees a new key also sees the new library
     tmp = lib + f".tmp{os.getpid()}"
-    cmd = ["g++"] + (opt or ["-O3", "-march=native"]) + \
-        ["-shared", "-fPIC", "-std=c++17", "-o", tmp, src] + (extra or [])
+    cmd = ["g++"] + flags + ["-o", tmp, src]
     if verbose:
         print("+", " ".join(cmd), file=sys.stderr)
     subprocess.run(cmd, check=True, capture_output=not verbose)
     os.replace(tmp, lib)
+    with open(tmp + ".key", "w", encoding="utf-8") as f:
+        f.write(key)
+    os.replace(tmp + ".key", lib + ".key")
     return lib
 
 

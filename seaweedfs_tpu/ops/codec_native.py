@@ -48,7 +48,7 @@ class NativeCodec:
                      shards: np.ndarray) -> np.ndarray:
         coef = np.asarray(coef, dtype=np.uint8)
         shards = np.asarray(shards, dtype=np.uint8)
-        if shards.shape[1] and native.has_scheduled():
+        if shards.shape[1]:
             # sample columns derive from a BYTE cap, and the verdict is
             # keyed by the sample's own size — the cached decision is
             # only ever one that was actually measured at that size

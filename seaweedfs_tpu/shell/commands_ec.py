@@ -263,10 +263,13 @@ def ec_verify(env: CommandEnv, volume_id: int, sample_mb: int = 4,
               backend: str = "numpy", quarantine: bool = True) -> dict:
     """Parity-check an EC volume's spread shards: fetch the same
     aligned prefix of every shard from its holder and run the codec
-    backend's RS verify (batched GF(256) matmul — `-backend=jax` puts
-    the check on the TPU). Any aligned prefix of all 14 shards is
-    itself a valid codeword set, so `sample_mb` bounds IO while still
-    exercising every shard end-to-end; 0 means full shards.
+    backend's RS verify (batched GF(256) matmul) in this shell
+    process. A device backend is refused unless JAX_PLATFORMS=cpu
+    asked for the CPU: the chip belongs to the volume server, and a
+    shell that reached for it would fail or take it away. Any aligned
+    prefix of all 14 shards is itself a valid codeword set, so
+    `sample_mb` bounds IO while still exercising every shard
+    end-to-end; 0 means full shards.
 
     With ``quarantine`` (default), a parity mismatch that pinpoints to
     exactly one corrupt shard deletes that shard on its holder and
@@ -274,9 +277,15 @@ def ec_verify(env: CommandEnv, volume_id: int, sample_mb: int = 4,
     reporting the failure."""
     import numpy as np
 
-    from ..ec.backend import ReedSolomon
+    from ..ec.backend import DEVICE_BACKENDS, ReedSolomon
+    from ..ops import device
     from ..rpc.httpclient import session
 
+    if backend in DEVICE_BACKENDS and not device.cpu_forced():
+        raise ShellError(
+            f"ec.verify -backend={backend}: the shell does not run "
+            "device codecs — the chip belongs to the volume server; "
+            "use -backend=native or -backend=numpy")
     _col, code, locs = env.ec_full_info(volume_id)
     k, m = code.k, code.m
     missing = [sid for sid in range(k + m) if sid not in locs]

@@ -51,7 +51,7 @@ HELP = """commands:
   volume.scrub [-volumeId=N] [-collection=C] [-limit=N]
                                     full-read CRC verification
   ec.encode -volumeId=N [-codec=k.m]  erasure-code a volume (wide tier)
-  ec.verify -volumeId=N [-sampleMB=4] [-backend=numpy|native|jax]
+  ec.verify -volumeId=N [-sampleMB=4] [-backend=numpy|native]
                                     parity-check spread shards
   ec.rebuild -volumeId=N            rebuild missing shards
   ec.balance                        even out shard counts

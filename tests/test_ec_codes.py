@@ -280,8 +280,7 @@ def test_native_scheduled_kernel_matches_oracle(rng):
     from seaweedfs_tpu import native
 
     try:
-        if not native.has_scheduled():
-            pytest.skip("native library lacks the scheduled kernel")
+        native.load()
     except Exception as e:
         pytest.skip(f"native library unavailable: {e}")
     code = geo.parse_code(LRC)
@@ -447,15 +446,12 @@ def test_native_sample_cap_keys_verdict_by_probed_size(rng, monkeypatch):
     """Requests past MEASURE_BYTES_MAX are decided from a byte-capped
     sample and the cached verdict is keyed by the SAMPLE's size — the
     chooser only ever records sizes it actually measured."""
-    from seaweedfs_tpu import native
     from seaweedfs_tpu.ops import codec_native
 
     try:
         codec = codec_native.NativeCodec()
     except Exception as e:
         pytest.skip(f"native codec unavailable: {e}")
-    if not native.has_scheduled():
-        pytest.skip("scheduled kernel not in this libgf256 build")
     monkeypatch.setenv("SEAWEEDFS_TPU_EC_SCHEDULE", "auto")
     coef = rs_matrix.parity_rows(10, 4)
     width = schedule.MEASURE_BYTES_MAX // 10 * 2  # 2x the sample cap

@@ -205,11 +205,13 @@ class TestEdgeCases:
                          params={"mv.from": "/renmnt/orig.txt"},
                          ).raise_for_status()
             deadline = time.monotonic() + 10
-            target = root / "moved.txt"
-            while time.monotonic() < deadline and not target.exists():
+            target, orig = root / "moved.txt", root / "orig.txt"
+            # the worker copies, then deletes: wait for both steps
+            while time.monotonic() < deadline and \
+                    (not target.exists() or orig.exists()):
                 time.sleep(0.05)
             assert target.read_bytes() == b"remote-only bytes"
-            assert not (root / "orig.txt").exists()
+            assert not orig.exists()
         finally:
             w.stop()
             run_command(env, "remote.unmount -dir=/renmnt")
