@@ -1,0 +1,7 @@
+"""Idle share of the device over the traced rebuild window: 1 - busy /
+window, busy the union of the XLA ops on the TPU (trace_reduce.py)."""
+from benchmark.roofline import idle_pct
+
+
+def read(run):
+    return idle_pct(run, "rebuild")
