@@ -31,10 +31,12 @@ _FUNCS = {"counter_add", "gauge_set", "histogram_observe"}
 # quantile points {0.5, 0.9, 0.99} the workload sketches export).
 # `stage` also carries the write-commit pipeline's fixed set
 # {queue, fsync, replicate, ack} — bounded by the pipeline shape,
-# never per-request data.
+# never per-request data. `name` is a phase or step name of
+# utils/tracing (span_seconds): string literals at the span/interval
+# call sites, a fixed set by construction.
 ALLOWED = {
     "backend", "code", "collection", "dir", "direction", "from",
-    "handler", "instance", "kind", "le", "method", "mode", "op",
+    "handler", "instance", "kind", "le", "method", "mode", "name", "op",
     "outcome", "q", "reason", "service", "shard", "stage", "tenant",
     "tier", "to",
 }

@@ -26,7 +26,7 @@ import time as _time
 import numpy as np
 
 from ..storage import needle_map
-from ..utils import tracing
+from ..utils import metrics, tracing
 from . import geometry as geo
 from .backend import ReedSolomon, get_backend
 
@@ -81,15 +81,19 @@ class _AsyncWriter:
             f, arr = item
             if not self._err:
                 try:
-                    view = _contig_view(arr)
-                    # raw (buffering=0) files may short-write (e.g.
-                    # ENOSPC partway); loop or the next block lands at
-                    # the wrong offset and the shard silently corrupts
-                    while len(view):
-                        n = f.write(view)
-                        if n is None or n == len(view):
-                            break
-                        view = view[n:]
+                    with tracing.interval("ec.shard_write"):
+                        view = _contig_view(arr)
+                        # raw (buffering=0) files may short-write (e.g.
+                        # ENOSPC partway); loop or the next block lands
+                        # at the wrong offset and the shard silently
+                        # corrupts
+                        while len(view):
+                            n = f.write(view)
+                            if n is None or n == len(view):
+                                break
+                            view = view[n:]
+                    metrics.counter_add("ec_shard_write_bytes_total",
+                                        arr.nbytes)
                 except BaseException as e:  # noqa: BLE001 - close re-raises
                     self._err.append(e)
             with self._cond:
@@ -98,8 +102,12 @@ class _AsyncWriter:
 
     def put(self, f, arr: np.ndarray) -> None:
         with self._cond:
-            while self._bytes >= self._max and not self._err:
-                self._cond.wait()
+            if self._bytes >= self._max and not self._err:
+                # the producer's backpressure wait, timed only when it
+                # happens
+                with tracing.interval("ec.encode.write_wait"):
+                    while self._bytes >= self._max and not self._err:
+                        self._cond.wait()
             self._bytes += arr.nbytes
         qi = self._affinity.get(id(f))
         if qi is None:
@@ -158,6 +166,14 @@ def write_ec_files(base: str, backend: str = "auto",
     """Generate .ec00..ecNN from `base`.dat (WriteEcFiles equivalent).
     `codec` selects the code family: "k.m" a (wide) RS, "lrc-k.l.g" an
     LRC; default RS(10,4)."""
+    with tracing.span("ec.write_ec_files", kind="internal") as rec:
+        _write_ec_files(base, backend, large_block, small_block, chunk,
+                        codec, rec)
+
+
+def _write_ec_files(base: str, backend: str, large_block: int,
+                    small_block: int, chunk: int, codec: str,
+                    rec: dict) -> None:
     code = geo.parse_code(codec or "")
     k, m = code.k, code.m
     # identity is the CODE, not (k, m): lrc-10.2.2 shares RS(10,4)'s
@@ -192,6 +208,7 @@ def write_ec_files(base: str, backend: str = "auto",
         else:
             rs.backend._resolve()
         backend_name = getattr(rs.backend, "chosen", "") or ""
+    rec["peer"] = backend_name
     if backend_name == "native" and dat_size:
         # the whole read -> parity -> write loop in one native call:
         # no GIL on either the producer or writer side (the measured
@@ -219,11 +236,9 @@ def write_ec_files(base: str, backend: str = "auto",
     outs = [open(base + geo.shard_ext(i), "wb", buffering=0)
             for i in range(k + m)]
     try:
-        with tracing.span("ec.write_ec_files", kind="internal",
-                          peer=backend_name):
-            _encode_region(rs, dat, 0, n_large, large_block, chunk, outs)
-            _encode_region(rs, dat, n_large * large_block * k,
-                           n_small, small_block, chunk, outs)
+        _encode_region(rs, dat, 0, n_large, large_block, chunk, outs)
+        _encode_region(rs, dat, n_large * large_block * k,
+                       n_small, small_block, chunk, outs)
     finally:
         for f in outs:
             f.close()
@@ -254,7 +269,10 @@ def _region_blocks(dat: np.ndarray, start: int, n_rows: int,
             row_start = start + r * row_bytes
             for c0 in range(0, block, chunk):
                 c1 = min(c0 + chunk, block)
-                yield _gather_columns(dat, row_start, block, c0, c1, k)
+                with tracing.interval("ec.encode.gather"):
+                    cols = _gather_columns(dat, row_start, block, c0, c1,
+                                           k)
+                yield cols
         return
     if not wide:
         for r in range(n_rows):
@@ -276,18 +294,20 @@ def _region_blocks(dat: np.ndarray, start: int, n_rows: int,
         span_start = start + r0 * row_bytes
         span_len = (r1 - r0) * row_bytes
         avail = max(0, min(span_len, total - span_start))
-        if avail == span_len:
-            # full span: transpose straight off the memmap — one
-            # strided copy instead of flat-copy + transpose-copy
-            flat = dat[span_start:span_start + span_len]
-        else:
-            flat = np.zeros(span_len, dtype=np.uint8)
-            if avail:
-                flat[:avail] = dat[span_start:span_start + avail]
-        # (rows, k, block) -> (k, rows*block): row-major per shard
-        yield np.ascontiguousarray(
-            flat.reshape(r1 - r0, k, block).transpose(1, 0, 2)
-            .reshape(k, (r1 - r0) * block))
+        with tracing.interval("ec.encode.gather"):
+            if avail == span_len:
+                # full span: transpose straight off the memmap — one
+                # strided copy instead of flat-copy + transpose-copy
+                flat = dat[span_start:span_start + span_len]
+            else:
+                flat = np.zeros(span_len, dtype=np.uint8)
+                if avail:
+                    flat[:avail] = dat[span_start:span_start + avail]
+            # (rows, k, block) -> (k, rows*block): row-major per shard
+            cols = np.ascontiguousarray(
+                flat.reshape(r1 - r0, k, block).transpose(1, 0, 2)
+                .reshape(k, (r1 - r0) * block))
+        yield cols
 
 
 def _encode_region(rs: ReedSolomon, dat: np.ndarray, start: int, n_rows: int,

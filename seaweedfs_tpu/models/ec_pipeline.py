@@ -97,7 +97,7 @@ def _staged_feed(blocks, upload, drain, depth: int, backend: str):
     from collections import deque
     from concurrent.futures import ThreadPoolExecutor
 
-    from ..ops.codec_jax import observe_stage
+    from ..ops.codec_jax import observe_stage, stage
 
     up_ex = ThreadPoolExecutor(max_workers=1,
                                thread_name_prefix="ecfeed-h2d")
@@ -113,12 +113,11 @@ def _staged_feed(blocks, upload, drain, depth: int, backend: str):
     it = iter(blocks)
     try:
         while True:
-            t0 = time.perf_counter()
             try:
-                block = next(it)
+                with stage(backend, "pread"):
+                    block = next(it)
             except StopIteration:
                 break
-            observe_stage(backend, "pread", time.perf_counter() - t0)
             pending.append(down_ex.submit(drain, up_ex.submit(upload,
                                                               block)))
             while len(pending) >= max(1, depth):
@@ -146,7 +145,7 @@ def pipelined_encode_stream(stripe_blocks, k: int = 10, m: int = 4,
 
     from jax.sharding import SingleDeviceSharding
 
-    from ..ops.codec_jax import _readback, observe_stage
+    from ..ops.codec_jax import _readback, stage
 
     if mesh is not None:
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -167,28 +166,25 @@ def pipelined_encode_stream(stripe_blocks, k: int = 10, m: int = 4,
         backend = "ec_pipeline"
 
     def upload(block):
-        t0 = time.perf_counter()
-        block = np.ascontiguousarray(block)
-        orig = None
-        if mesh is not None:
-            block, orig = pad_to_mesh(block, mesh)
-        dev = jax.device_put(block, sharding)
-        jax.block_until_ready(dev)
-        observe_stage(backend, "h2d", time.perf_counter() - t0)
+        with stage(backend, "h2d"):
+            block = np.ascontiguousarray(block)
+            orig = None
+            if mesh is not None:
+                block, orig = pad_to_mesh(block, mesh)
+            dev = jax.device_put(block, sharding)
+            jax.block_until_ready(dev)
         return fn(a_bits, dev), orig
 
     def drain(up_fut):
         out, orig = up_fut.result()
-        t0 = time.perf_counter()
-        jax.block_until_ready(out)
-        t1 = time.perf_counter()
-        observe_stage(backend, "kernel", t1 - t0)
-        host = _readback(out)
-        if orig is not None and (host.shape[0], host.shape[2]) != orig:
-            host = np.ascontiguousarray(host[:orig[0], :, :orig[1]])
-        t2 = time.perf_counter()
-        observe_stage(backend, "d2h", t2 - t1)
-        return host, t2
+        with stage(backend, "drain_wait"):
+            jax.block_until_ready(out)
+        with stage(backend, "d2h"):
+            host = _readback(out)
+            if orig is not None and \
+                    (host.shape[0], host.shape[2]) != orig:
+                host = np.ascontiguousarray(host[:orig[0], :, :orig[1]])
+        return host, time.perf_counter()
 
     yield from _staged_feed(stripe_blocks, upload, drain, depth,
                             backend)
@@ -211,7 +207,7 @@ def pipelined_scrub(pair_blocks, k: int = 10, m: int = 4,
 
     from jax.sharding import SingleDeviceSharding
 
-    from ..ops.codec_jax import observe_stage
+    from ..ops.codec_jax import stage
 
     if mesh is not None:
         from ..parallel.mesh import pad_to_mesh
@@ -228,28 +224,24 @@ def pipelined_scrub(pair_blocks, k: int = 10, m: int = 4,
 
     def upload(pair):
         stripes, expected = pair
-        t0 = time.perf_counter()
-        stripes = np.ascontiguousarray(stripes)
-        expected = np.ascontiguousarray(expected)
-        if mesh is not None:
-            stripes, _ = pad_to_mesh(stripes, mesh)
-            expected, _ = pad_to_mesh(expected, mesh)
-        dev_s = jax.device_put(stripes, sharding)
-        dev_e = jax.device_put(expected, sharding)
-        jax.block_until_ready((dev_s, dev_e))
-        observe_stage(backend, "h2d", time.perf_counter() - t0)
+        with stage(backend, "h2d"):
+            stripes = np.ascontiguousarray(stripes)
+            expected = np.ascontiguousarray(expected)
+            if mesh is not None:
+                stripes, _ = pad_to_mesh(stripes, mesh)
+                expected, _ = pad_to_mesh(expected, mesh)
+            dev_s = jax.device_put(stripes, sharding)
+            dev_e = jax.device_put(expected, sharding)
+            jax.block_until_ready((dev_s, dev_e))
         return step(a_bits, dev_s, dev_e)
 
     def drain(up_fut):
         _parity, mism = up_fut.result()
-        t0 = time.perf_counter()
-        jax.block_until_ready(mism)
-        t1 = time.perf_counter()
-        observe_stage(backend, "kernel", t1 - t0)
-        val = int(mism)
-        t2 = time.perf_counter()
-        observe_stage(backend, "d2h", t2 - t1)
-        return val, t2
+        with stage(backend, "drain_wait"):
+            jax.block_until_ready(mism)
+        with stage(backend, "d2h"):
+            val = int(mism)
+        return val, time.perf_counter()
 
     total = 0
     n = 0

@@ -27,14 +27,17 @@ can reuse them for the bit-plane intermediate, and readback goes
 through dlpack when the consumer and producer share an address space
 (CPU devices: zero-copy). Every stage is timed into
 ec_codec_stage_seconds{stage,backend} — pread (waiting on the block
-source), h2d, kernel, d2h, and relay (finished results waiting for the
-consumer) — which is what lets bench/VERDICT attribute the
-encode-vs-ceiling gap instead of guessing.
+source), h2d, drain_wait (the drain thread waiting for the kernel,
+which overlaps it), d2h, and relay (finished results waiting for the
+consumer) — and, but for relay, which no thread is inside, opens a
+`swfs.ec.stage.<stage>` profiler annotation, so a trace shows the
+kernel's own time beside the host stage around it.
 """
 from __future__ import annotations
 
 import time as _time
 from collections import OrderedDict
+from contextlib import contextmanager
 from functools import partial
 
 import jax
@@ -42,7 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import gf256, schedule
-from ..utils import metrics
+from ..utils import metrics, tracing
 
 # Column slab each jitted call processes; callers pad up to a multiple.
 # 2 MiB columns x 8k bit-rows in bf16 keeps the working set well inside HBM
@@ -89,12 +92,20 @@ _xor_matmul = jax.jit(_xor_matmul_body, static_argnums=(0,))
 
 
 def observe_stage(backend: str, stage: str, seconds: float) -> None:
-    """Per-stage feed timing (pread/h2d/kernel/d2h/relay) — the
-    attribution VERDICT round 5 asked for. Lives next to
-    ec_codec_seconds; one extra label dimension, one histogram per
-    (stage, backend)."""
+    """Per-stage feed timing (pread/h2d/drain_wait/d2h/relay). Lives
+    next to ec_codec_seconds; one extra label dimension, one histogram
+    per (stage, backend)."""
     metrics.histogram_observe("ec_codec_stage_seconds", seconds,
                               {"stage": stage, "backend": backend})
+
+
+@contextmanager
+def stage(backend: str, name: str):
+    """One feed stage on this thread: the `swfs.ec.stage.<name>`
+    annotation over it, its seconds into ec_codec_stage_seconds."""
+    with tracing.interval("ec.stage." + name, observe=False) as iv:
+        yield
+    observe_stage(backend, name, iv.seconds)
 
 
 def bit_matrix(coef: np.ndarray) -> jax.Array:
@@ -264,7 +275,7 @@ class JaxCodec:
           upload thread   h2d     committed device_put, blocks until
                                   the copy lands, then issues the
                                   kernel (async under jax dispatch)
-          drain thread    kernel  block_until_ready on the result
+          drain thread    drain_wait  block_until_ready on the result
                           d2h     dlpack/np.asarray readback
 
         While the drain thread reads block k-1 back, the device runs
@@ -272,8 +283,9 @@ class JaxCodec:
         double-buffered schedule at depth=2, deeper when asked. Each
         stage records ec_codec_stage_seconds{stage}; `relay` is the
         time a finished block waited for the consumer (writer
-        backpressure + queue residence), so pread+h2d+kernel+d2h+relay
-        accounts for the whole e2e gap versus the link ceiling.
+        backpressure + queue residence), so
+        pread+h2d+drain_wait+d2h+relay accounts for the whole e2e gap
+        versus the link ceiling.
         """
         from collections import deque
         from concurrent.futures import Future, ThreadPoolExecutor
@@ -287,30 +299,25 @@ class JaxCodec:
         plan = self._plan_for(coef, coef.shape[1] * self.slab)
 
         def upload(block: np.ndarray):
-            t0 = _time.perf_counter()
-            chunks = self._split(block)
-            devs = [(self._h2d(chunk), w) for chunk, w in chunks]
-            for d, _ in devs:
-                # wait for the copies, not the compute: the h2d stage
-                # time must be the transfer alone, and issuing the next
-                # upload before the kernel keeps the DMA engine busy
-                d.block_until_ready()
-            t1 = _time.perf_counter()
-            outs = [(self._run(mats, d, plan), w) for d, w in devs]
-            observe_stage(backend, "h2d", t1 - t0)
-            return outs
+            with stage(backend, "h2d"):
+                chunks = self._split(block)
+                devs = [(self._h2d(chunk), w) for chunk, w in chunks]
+                for d, _ in devs:
+                    # wait for the copies, not the compute: the h2d
+                    # stage time must be the transfer alone, and issuing
+                    # the next upload before the kernel keeps the DMA
+                    # engine busy
+                    d.block_until_ready()
+            return [(self._run(mats, d, plan), w) for d, w in devs]
 
         def drain(up_fut):
             outs = up_fut.result()
-            t0 = _time.perf_counter()
-            for d, _ in outs:
-                d.block_until_ready()
-            t1 = _time.perf_counter()
-            arr = _collect(outs)
-            t2 = _time.perf_counter()
-            observe_stage(backend, "kernel", t1 - t0)
-            observe_stage(backend, "d2h", t2 - t1)
-            return arr, t2
+            with stage(backend, "drain_wait"):
+                for d, _ in outs:
+                    d.block_until_ready()
+            with stage(backend, "d2h"):
+                arr = _collect(outs)
+            return arr, _time.perf_counter()
 
         up_ex = ThreadPoolExecutor(1, thread_name_prefix="ec-h2d")
         down_ex = ThreadPoolExecutor(1, thread_name_prefix="ec-d2h")
@@ -326,13 +333,11 @@ class JaxCodec:
             pending: deque = deque()
             it = iter(blocks)
             while True:
-                t0 = _time.perf_counter()
                 try:
-                    block = next(it)
+                    with stage(backend, "pread"):
+                        block = next(it)
                 except StopIteration:
                     break
-                observe_stage(backend, "pread",
-                              _time.perf_counter() - t0)
                 block = np.asarray(block, dtype=np.uint8)
                 if block.shape[1] == 0:
                     # empty result still rides the queue: yielding it

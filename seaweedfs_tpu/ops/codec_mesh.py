@@ -269,7 +269,7 @@ class MeshCodec:
         from collections import deque
         from concurrent.futures import Future, ThreadPoolExecutor
 
-        from .codec_jax import observe_stage
+        from .codec_jax import observe_stage, stage
 
         coef = np.asarray(coef, dtype=np.uint8)
         m = coef.shape[0]
@@ -281,25 +281,19 @@ class MeshCodec:
             coef, coef.shape[1] * self.n_devices * (1 << 20))
 
         def upload(block: np.ndarray):
-            t0 = _time.perf_counter()
-            batched, _per = self._to_batched(block)
-            dev = self._h2d(batched)
-            dev.block_until_ready()
-            t1 = _time.perf_counter()
-            out = self._kernel_call(mats, plan, dev)
-            observe_stage(backend, "h2d", t1 - t0)
-            return out
+            with stage(backend, "h2d"):
+                batched, _per = self._to_batched(block)
+                dev = self._h2d(batched)
+                dev.block_until_ready()
+            return self._kernel_call(mats, plan, dev)
 
         def drain(up_fut, n: int):
             out = up_fut.result()
-            t0 = _time.perf_counter()
-            out.block_until_ready()
-            t1 = _time.perf_counter()
-            arr = self._from_batched(np.asarray(out), n)
-            t2 = _time.perf_counter()
-            observe_stage(backend, "kernel", t1 - t0)
-            observe_stage(backend, "d2h", t2 - t1)
-            return arr, t2
+            with stage(backend, "drain_wait"):
+                out.block_until_ready()
+            with stage(backend, "d2h"):
+                arr = self._from_batched(np.asarray(out), n)
+            return arr, _time.perf_counter()
 
         up_ex = ThreadPoolExecutor(1, thread_name_prefix="ecmesh-h2d")
         down_ex = ThreadPoolExecutor(1, thread_name_prefix="ecmesh-d2h")
@@ -315,13 +309,11 @@ class MeshCodec:
             pending: deque = deque()
             it = iter(blocks)
             while True:
-                t0 = _time.perf_counter()
                 try:
-                    block = next(it)
+                    with stage(backend, "pread"):
+                        block = next(it)
                 except StopIteration:
                     break
-                observe_stage(backend, "pread",
-                              _time.perf_counter() - t0)
                 block = np.asarray(block, dtype=np.uint8)
                 if block.shape[1] == 0:
                     # empty block still rides the queue so ordering

@@ -34,6 +34,9 @@ import jax.numpy as jnp
 import numpy as np
 
 COL_TILE = 4096  # lanes per grid step
+# the kernel's op name in compiled HLO and in a device trace
+# ("%ec_coded_matmul.N"), whatever the Python wrapper is called
+KERNEL_NAME = "ec_coded_matmul"
 
 
 def _kernel(a_ref, p_ref, x_ref, o_ref):
@@ -104,6 +107,7 @@ def _coded_matmul_pallas_pm_impl(a_pm: jax.Array, pack: jax.Array,
         ],
         out_specs=pl.BlockSpec((m, COL_TILE), lambda j: (0, j)),
         interpret=interpret,
+        name=KERNEL_NAME,
     )(a_pm, pack, shards)
 
 

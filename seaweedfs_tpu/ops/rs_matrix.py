@@ -62,16 +62,6 @@ _inv_cache: "OrderedDict[tuple, bytes]" = OrderedDict()
 _inv_lock = threading.Lock()
 
 
-def _count_inv(outcome: str) -> None:
-    try:
-        from ..utils import metrics
-
-        metrics.counter_add("rs_matrix_inversion_cache_total", 1,
-                            {"outcome": outcome})
-    except Exception:  # pragma: no cover - metrics must never fatal
-        pass
-
-
 def _cached_inverse(key: tuple, sub: np.ndarray) -> np.ndarray:
     """LRU-cached gf256.mat_inv keyed by the surviving-shard set (plus
     the code identity): a repair storm over one loss pattern hits the
@@ -81,11 +71,9 @@ def _cached_inverse(key: tuple, sub: np.ndarray) -> np.ndarray:
         if raw is not None:
             _inv_cache.move_to_end(key)
     if raw is not None:
-        _count_inv("hit")
         n = sub.shape[0]
         return np.frombuffer(raw, dtype=np.uint8).reshape(n, n).copy()
     inv = gf256.mat_inv(sub)
-    _count_inv("miss")
     with _inv_lock:
         _inv_cache[key] = inv.tobytes()
         while len(_inv_cache) > INVERSION_CACHE_MAX:
@@ -285,7 +273,6 @@ def recovery_rows_for(code, present: list[int], missing: list[int]
         if raw is not None:
             _inv_cache.move_to_end(key)
     if raw is not None:
-        _count_inv("hit")
         rows = np.frombuffer(raw, dtype=np.uint8).reshape(
             len(missing), len(inputs)).copy()
         return rows, inputs
@@ -295,7 +282,6 @@ def recovery_rows_for(code, present: list[int], missing: list[int]
         raise ValueError(
             f"code {code.spec}: no solution for {missing} from {inputs}")
     rows = np.ascontiguousarray(x.T)
-    _count_inv("miss")
     with _inv_lock:
         _inv_cache[key] = rows.tobytes()
         while len(_inv_cache) > INVERSION_CACHE_MAX:

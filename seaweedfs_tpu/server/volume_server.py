@@ -1646,6 +1646,44 @@ class VolumeServer:
         self.poke_heartbeat()
         return web.json_response(result)
 
+    def _fetch_ec_index_sync(self, vid: int, collection: str, base: str,
+                             hosts: list[str], max_bps: float) -> int:
+        """Copy the .ecx (and the .vif, when any holder has one) of
+        `vid` from the first of `hosts` that serves it; the bytes
+        copied."""
+        from ..rpc.httpclient import session
+
+        copied = 0
+        for ext in (".ecx", ".vif"):
+            blob = None
+            for h in hosts:
+                try:
+                    r = session().get(
+                        f"http://{h}/admin/copy_file",
+                        params={"volume": vid,
+                                "collection": collection,
+                                "ext": ext, "bps": max_bps},
+                        timeout=60)
+                except Exception:
+                    continue
+                if r.status_code == 200:
+                    blob = r.content
+                    break
+            if blob is None:
+                if ext == ".ecx":
+                    raise ValueError(f"vid {vid}: no holder "
+                                     f"serves .ecx")
+                try:  # no .vif anywhere = default RS(10,4)
+                    os.unlink(base + ".vif")
+                except FileNotFoundError:
+                    pass
+                continue
+            with open(base + ext, "wb") as f:
+                f.write(blob)
+            self._repair_throttle_sync(max_bps, len(blob))
+            copied += len(blob)
+        return copied
+
     def _partial_ec_rebuild_sync(self, vid: int, collection: str,
                                  missing: list[int], max_bps: float,
                                  chunk: int) -> dict:
@@ -1679,37 +1717,13 @@ class VolumeServer:
                 if u not in hosts:
                     hosts.append(u)
         net_bytes = 0
+        local_bytes = 0
         # the sorted needle index (and codec sidecar) must exist
         # locally before the rebuilt shard can be mounted
         if not os.path.exists(base + ".ecx"):
-            for ext in (".ecx", ".vif"):
-                blob = None
-                for h in hosts:
-                    try:
-                        r = session().get(
-                            f"http://{h}/admin/copy_file",
-                            params={"volume": vid,
-                                    "collection": collection,
-                                    "ext": ext, "bps": max_bps},
-                            timeout=60)
-                    except Exception:
-                        continue
-                    if r.status_code == 200:
-                        blob = r.content
-                        break
-                if blob is None:
-                    if ext == ".ecx":
-                        raise ValueError(f"vid {vid}: no holder "
-                                         f"serves .ecx")
-                    try:  # no .vif anywhere = default RS(10,4)
-                        os.unlink(base + ".vif")
-                    except FileNotFoundError:
-                        pass
-                    continue
-                with open(base + ext, "wb") as f:
-                    f.write(blob)
-                self._repair_throttle_sync(max_bps, len(blob))
-                net_bytes += len(blob)
+            with tracing.interval("ec.rebuild.fetch_index"):
+                net_bytes += self._fetch_ec_index_sync(
+                    vid, collection, base, hosts, max_bps)
         code = code_of(base)
         k, m = code.k, code.m
         avail = sorted(set(local_sids) | set(remote_sids))
@@ -1770,26 +1784,38 @@ class VolumeServer:
             split_plan()
         fetch_deadline = max(30.0, self.store.ec_read_deadline)
 
+        def read_local(s: int, off: int, n: int):
+            nonlocal local_bytes
+            with tracing.interval("ec.rebuild.read_local"):
+                row = np.frombuffer(ecv.shards[s].read_at(off, n),
+                                    dtype=np.uint8)
+            local_bytes += len(row)
+            return row
+
+        def fetch(sids: list[int], off: int, n: int, need: int) -> dict:
+            nonlocal net_bytes
+            with tracing.interval("ec.rebuild.fetch"):
+                # pace the loop BEFORE the fan-out so the burst the
+                # fetch admits is already paid for
+                self._repair_throttle_sync(max_bps, need * n)
+                fetched = self._remote_shards_fetch_sync(
+                    vid, sids, off, n, need=need,
+                    deadline=fetch_deadline, bps=max_bps)
+            net_bytes += len(fetched) * n
+            return fetched
+
         def gather_planned(off: int, n: int):
             """Rows for one chunk via the repair plan, re-planning
             around unreachable remotes; None -> use the generic
             gather."""
-            nonlocal plan, net_bytes
+            nonlocal plan
             while plan is not None:
                 rows: dict[int, object] = {}
                 for s in plan_local:
-                    rows[s] = np.frombuffer(
-                        ecv.shards[s].read_at(off, n), dtype=np.uint8)
+                    rows[s] = read_local(s, off, n)
                 if not plan_remote:
                     return rows
-                # pace the loop BEFORE the fan-out so the burst the
-                # fetch admits is already paid for
-                self._repair_throttle_sync(max_bps,
-                                           len(plan_remote) * n)
-                fetched = self._remote_shards_fetch_sync(
-                    vid, plan_remote, off, n, need=len(plan_remote),
-                    deadline=fetch_deadline, bps=max_bps)
-                net_bytes += len(fetched) * n
+                fetched = fetch(plan_remote, off, n, len(plan_remote))
                 short = [s for s in plan_remote if s not in fetched]
                 if not short:
                     for s in plan_remote:
@@ -1807,7 +1833,6 @@ class VolumeServer:
             """Span-growing gather over ALL reachable shards (dead
             ones included — they may only have been slow): rank k over
             the code's encode rows, which for RS is plain first-k."""
-            nonlocal net_bytes
             from ..ops import rs_matrix
 
             rows: dict[int, object] = {}
@@ -1822,17 +1847,11 @@ class VolumeServer:
 
             for s in local_sids:
                 if grows(s):
-                    rows[s] = np.frombuffer(
-                        ecv.shards[s].read_at(off, n), dtype=np.uint8)
+                    rows[s] = read_local(s, off, n)
                     span.append(s)
             cands = list(remote_sids)
             while len(span) < k and cands:
-                need = k - len(span)
-                self._repair_throttle_sync(max_bps, need * n)
-                fetched = self._remote_shards_fetch_sync(
-                    vid, cands, off, n, need=need,
-                    deadline=fetch_deadline, bps=max_bps)
-                net_bytes += len(fetched) * n
+                fetched = fetch(cands, off, n, k - len(span))
                 if not fetched:
                     break
                 for s in sorted(fetched):
@@ -1857,11 +1876,13 @@ class VolumeServer:
                     else None
                 if rows is None:
                     rows = gather_generic(off, n)
-                rec = rs.reconstruct(rows, missing=missing)
-                for s in missing:
-                    row = np.asarray(rec[s], dtype=np.uint8).tobytes()
-                    files[s].write(row)
-                    written += len(row)
+                with tracing.interval("ec.rebuild.reconstruct"):
+                    rec = rs.reconstruct(rows, missing=missing)
+                with tracing.interval("ec.rebuild.write"):
+                    for s in missing:
+                        row = np.asarray(rec[s], dtype=np.uint8).tobytes()
+                        files[s].write(row)
+                        written += len(row)
         except Exception:
             for s, f in files.items():
                 f.close()
@@ -1877,6 +1898,10 @@ class VolumeServer:
         lab = {"mode": "partial", "code": code.spec}
         metrics.counter_add("ec_repair_read_bytes_by_code_total",
                             net_bytes, lab)
+        # the shards the rebuilder holds itself, which the counters
+        # above leave out: the two together are the plan's reads
+        metrics.counter_add("ec_repair_local_read_bytes_total",
+                            local_bytes, {"code": code.spec})
         return {"rebuilt_shards": missing, "rebuilt_bytes": written,
                 "read_bytes": net_bytes}
 

@@ -10,6 +10,7 @@ from __future__ import annotations
 from collections import defaultdict
 
 from ..ec import geometry as geo
+from ..utils import tracing
 from .env import CommandEnv, ShellError
 
 
@@ -29,23 +30,31 @@ def ec_encode(env: CommandEnv, volume_id: int,
         codec = default_code_spec()
     k, m = geo.parse_codec(codec)
     total = k + m
-    sources = env.volume_locations(volume_id)
-    if not sources:
-        raise ShellError(f"volume {volume_id} not found")
-    if not collection:
-        collection = env.volume_collection(volume_id)
-    for url in sources:
-        env.vs_post(url, "/admin/mark_readonly", {"volume": volume_id})
-    source = sources[0]
-    env.vs_post(source, "/admin/ec/generate",
-                {"volume": volume_id, "collection": collection,
-                 "codec": codec})
-    placement = spread_ec_shards(env, volume_id, collection, source,
-                                 total=total)
-    # delete original replicas now that shards are mounted
-    for url in sources:
-        env.vs_post(url, "/admin/delete_volume", {"volume": volume_id})
-    env.wait_for_ec_registration(volume_id, total)
+    with tracing.span("ec.encode"):
+        sources = env.volume_locations(volume_id)
+        if not sources:
+            raise ShellError(f"volume {volume_id} not found")
+        if not collection:
+            collection = env.volume_collection(volume_id)
+        with tracing.span("ec.encode.readonly"):
+            for url in sources:
+                env.vs_post(url, "/admin/mark_readonly",
+                            {"volume": volume_id})
+        source = sources[0]
+        with tracing.span("ec.encode.generate"):
+            env.vs_post(source, "/admin/ec/generate",
+                        {"volume": volume_id, "collection": collection,
+                         "codec": codec})
+        with tracing.span("ec.encode.spread"):
+            placement = spread_ec_shards(env, volume_id, collection,
+                                         source, total=total)
+        # delete original replicas now that shards are mounted
+        with tracing.span("ec.encode.delete_source"):
+            for url in sources:
+                env.vs_post(url, "/admin/delete_volume",
+                            {"volume": volume_id})
+        with tracing.span("ec.encode.wait_registration"):
+            env.wait_for_ec_registration(volume_id, total)
     return {sid: url for sid, url in placement.items()}
 
 
@@ -103,37 +112,46 @@ def ec_rebuild(env: CommandEnv, volume_id: int,
     instead of borrowing every surviving shard file; the classic
     full-stripe path remains as fallback (mode="full").  ``max_bps``
     shapes all transfers against each node's repair bucket."""
+    env.confirm_locked()
+    with tracing.span("ec.rebuild"):
+        return _ec_rebuild(env, volume_id, collection, max_bps, partial)
+
+
+def _ec_rebuild(env: CommandEnv, volume_id: int, collection: str,
+                max_bps: float, partial: bool) -> dict:
     from ..master import placement as pl
 
-    env.confirm_locked()
-    reg_collection, code, locations = env.ec_full_info(volume_id)
-    k, m = code.k, code.m
-    if not collection:
-        collection = reg_collection
-    present = set(locations)
-    missing = [sid for sid in range(k + m)
-               if sid not in present]
-    if not missing:
-        return {"rebuilt": []}
-    if not code.recoverable(sorted(present)):
-        raise ShellError(
-            f"volume {volume_id}: shards {sorted(present)} cannot "
-            f"rebuild {code.spec}")
-    nodes = env.data_nodes()
-    node, violations = pl.select_ec_rebuilder(nodes, volume_id,
-                                              locations)
-    if node is None:  # every node full: fall back to emptiest
-        node = max(nodes,
-                   key=lambda n: n["max_volumes"] - len(n["volumes"]))
-    rebuilder = node["url"]
+    with tracing.span("ec.rebuild.plan"):
+        reg_collection, code, locations = env.ec_full_info(volume_id)
+        k, m = code.k, code.m
+        if not collection:
+            collection = reg_collection
+        present = set(locations)
+        missing = [sid for sid in range(k + m)
+                   if sid not in present]
+        if not missing:
+            return {"rebuilt": []}
+        if not code.recoverable(sorted(present)):
+            raise ShellError(
+                f"volume {volume_id}: shards {sorted(present)} cannot "
+                f"rebuild {code.spec}")
+        nodes = env.data_nodes()
+        node, violations = pl.select_ec_rebuilder(nodes, volume_id,
+                                                  locations)
+        if node is None:  # every node full: fall back to emptiest
+            node = max(nodes,
+                       key=lambda n: n["max_volumes"] - len(n["volumes"]))
+        rebuilder = node["url"]
     if partial and len(missing) <= m:
         try:
-            out = env.vs_post(rebuilder, "/admin/ec/rebuild_partial",
-                              {"volume": volume_id,
-                               "collection": collection,
-                               "shard_ids": missing,
-                               "max_bps": max_bps})
-            env.wait_for_ec_registration(volume_id, k + m)
+            with tracing.span("ec.rebuild.partial"):
+                out = env.vs_post(rebuilder, "/admin/ec/rebuild_partial",
+                                  {"volume": volume_id,
+                                   "collection": collection,
+                                   "shard_ids": missing,
+                                   "max_bps": max_bps})
+            with tracing.span("ec.rebuild.wait_registration"):
+                env.wait_for_ec_registration(volume_id, k + m)
             return {"rebuilt": out["rebuilt_shards"],
                     "rebuilder": rebuilder, "mode": "partial",
                     "rebuilt_bytes": out.get("rebuilt_bytes", 0),
@@ -167,7 +185,8 @@ def ec_rebuild(env: CommandEnv, volume_id: int,
     if borrowed:
         env.vs_post(rebuilder, "/admin/ec/delete",
                     {"volume": volume_id, "shard_ids": borrowed})
-    env.wait_for_ec_registration(volume_id, k + m)
+    with tracing.span("ec.rebuild.wait_registration"):
+        env.wait_for_ec_registration(volume_id, k + m)
     return {"rebuilt": rebuilt, "rebuilder": rebuilder, "mode": "full",
             "rebuilt_bytes": out.get("rebuilt_bytes", 0),
             "placement_violations": violations}

@@ -24,6 +24,7 @@ from ..ec.backend import cpu_backend_name as ec_cpu_backend
 from ..ec.encoder import rebuild_ec_files, write_ec_files, write_sorted_ecx
 from ..ec.volume import EcVolume
 from ..utils import sketch as _sketch
+from ..utils import tracing
 from . import needle as ndl
 from . import types as t
 from .disk_location import DiskLocation
@@ -371,7 +372,8 @@ class Store:
         v.sync()
         base = v.file_name()
         write_ec_files(base, backend=self.ec_backend, codec=codec)
-        write_sorted_ecx(base)
+        with tracing.span("ec.write_sorted_ecx"):
+            write_sorted_ecx(base)
 
     def rebuild_ec_shards(self, vid: int) -> list[int]:
         """VolumeEcShardsRebuild (:84): regenerate missing local shards."""

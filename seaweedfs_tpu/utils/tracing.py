@@ -10,15 +10,27 @@ server, are summarized into ``request_trace_seconds{service,handler}``
 histograms, and — when a local root span exceeds the configurable slow
 threshold — emit one structured glog line carrying the full span tree.
 
-The core is importable without aiohttp; the middleware/handler factories
-import it lazily so `operation/` and the EC package can depend on this
-module from sync code.
+Work inside one process has two granularities. A phase (``span`` with
+``kind="internal"``: an EC encode, its spread, a partial rebuild) is a
+ring record like any other span; a per-chunk step (``interval``: one
+fetch, one shard write, one codec stage) is timed but never recorded
+in the ring, where hundreds of them would evict the request traces.
+Both observe ``span_seconds{name}`` and, when JAX is already loaded,
+open a ``jax.profiler.TraceAnnotation("swfs." + name)`` over the same
+interval, so a profiler trace names the host stage the device waited
+on, on the device's clock.
+
+The core is importable without aiohttp or JAX; the middleware/handler
+factories import aiohttp lazily, and JAX is never imported here, so
+`operation/` and the EC package can depend on this module from sync
+code.
 """
 from __future__ import annotations
 
 import contextvars
 import json
 import secrets
+import sys
 import threading
 import time
 from collections import deque
@@ -188,6 +200,50 @@ def inject(headers: dict) -> dict:
     return headers
 
 
+ANNOTATION_PREFIX = "swfs."
+
+
+def _annotation(name: str):
+    """An entered profiler annotation ``swfs.<name>``, or None when JAX
+    is not loaded in this process (it is never imported from here).
+    With the profiler off, entering one costs a TraceMe check."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return None
+    note = profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
+    note.__enter__()
+    return note
+
+
+class interval:
+    """Time one block on this thread: the ``swfs.<name>`` annotation
+    over it and, unless ``observe=False``, its seconds into
+    ``span_seconds{name}``; no ring record. For per-chunk steps of a
+    phase (``with tracing.interval("ec.rebuild.fetch"): ...``). After
+    the block, ``seconds`` holds its duration."""
+
+    __slots__ = ("name", "observe", "seconds", "_t0", "_note")
+
+    def __init__(self, name: str, observe: bool = True):
+        self.name = name
+        self.observe = observe
+        self.seconds = 0.0
+
+    def __enter__(self) -> "interval":
+        self._note = _annotation(self.name)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.seconds = time.perf_counter() - self._t0
+        if self._note is not None:
+            self._note.__exit__(*exc)
+        if self.observe:
+            metrics.histogram_observe("span_seconds", self.seconds,
+                                      {"name": self.name})
+        return False
+
+
 @contextmanager
 def span(name: str, *, service: str = "", kind: str = "internal",
          peer: str = "", remote: TraceContext | None = None):
@@ -196,6 +252,12 @@ def span(name: str, *, service: str = "", kind: str = "internal",
 
     Parentage: an explicit ``remote`` context (incoming traceparent)
     wins, else the contextvar parent, else a fresh root trace.
+
+    An internal span (a phase of work inside this process) also opens
+    the ``swfs.<name>`` profiler annotation and observes
+    ``span_seconds{name}``. Server and client spans do neither: they
+    cross ``await``s on the event-loop thread, where an annotation
+    would not nest.
     """
     parent = _current.get()
     if remote is not None:
@@ -218,6 +280,7 @@ def span(name: str, *, service: str = "", kind: str = "internal",
         "duration": 0.0,
         "status": "",
     }
+    note = _annotation(name) if kind == "internal" else None
     t0 = time.perf_counter()
     try:
         yield rec
@@ -226,6 +289,8 @@ def span(name: str, *, service: str = "", kind: str = "internal",
         raise
     finally:
         rec["duration"] = time.perf_counter() - t0
+        if note is not None:
+            note.__exit__(None, None, None)
         _current.reset(token)
         _finish(rec)
 
@@ -244,6 +309,9 @@ def _finish(rec: dict) -> None:
             "request_trace_seconds", rec["duration"],
             {"service": rec["service"] or "unknown",
              "handler": rec["name"] or "unknown"})
+    elif rec["kind"] == "internal":
+        metrics.histogram_observe("span_seconds", rec["duration"],
+                                  {"name": rec["name"]})
     if slow:
         _log_slow(rec)
     for sink in list(_sinks):

@@ -140,10 +140,25 @@ class TestPartialRepairTraffic:
         return metrics._counters.get(
             ("repair_read_bytes_total", (("mode", mode),)), 0.0)
 
+    @staticmethod
+    def _until(fn, what):
+        """The master learns of a shard's loss or return from the next
+        heartbeat: poll what it lists."""
+        import time
+
+        deadline = time.monotonic() + 20
+        while not fn():
+            assert time.monotonic() < deadline, what
+            time.sleep(0.05)
+
     def _drop_shard(self, env, vid, sid):
         for url in env.ec_shard_locations(vid).get(sid, []):
             env.vs_post(url, "/admin/ec/delete",
                         {"volume": vid, "shard_ids": [sid]})
+        # a rebuild planned before the master sees the loss finds
+        # nothing missing
+        self._until(lambda: not env.ec_shard_locations(vid).get(sid),
+                    f"shard {sid} still listed")
 
     def test_partial_moves_fewer_bytes_than_full(self, cluster, env,
                                                  sealed_volume):
@@ -235,10 +250,82 @@ class TestPartialRepairTraffic:
         healed = rebuilder.store.ec_volumes[vid].shards[1]
         assert healed.read_at(0, healed.size) == golden
         # the healed volume still serves reads
+        self._until(lambda: env.ec_shard_locations(vid).get(1),
+                    "rebuilt shard 1 never listed")
         locs2 = env.ec_shard_locations(vid)
         fid, data = next(iter(payloads.items()))
         assert requests.get(
             f"http://{locs2[1][0]}/{fid}").content == data
+
+    def test_partial_rebuild_steps_are_timed_per_chunk(
+            self, cluster, env, sealed_volume, monkeypatch):
+        """Each chunk of a partial rebuild is one fetch, one
+        reconstruct and one write interval (span_seconds); the local
+        and remote read counters add up to the repair plan's reads;
+        and the rebuild's trace in the ring holds its phases only,
+        however many chunks it took."""
+        from seaweedfs_tpu.utils import metrics, tracing
+
+        chunk = 256 << 10
+        steps = ("ec.rebuild.read_local", "ec.rebuild.fetch",
+                 "ec.rebuild.reconstruct", "ec.rebuild.write")
+
+        def count(name):
+            return metrics._counters.get(
+                ("span_seconds_count", (("name", name),)), 0.0)
+
+        def read_bytes(name, code):
+            return sum(v for (n, lab), v in metrics._counters.items()
+                       if n == name and ("code", code) in lab)
+
+        vid, payloads = sealed_volume
+        commands_ec.ec_encode(env, vid, codec="lrc-10.2.2")
+        _col, code, _locs = env.ec_full_info(vid)
+        self._drop_shard(env, vid, 1)
+        plan = code.repair_plan(
+            [1], [s for s in range(code.total) if s != 1])
+        assert plan is not None and plan.kind == "local"
+        # the shell sends no chunk size: ask for a small one, so the
+        # shard takes several chunks
+        post = env.vs_post
+
+        def small_chunks(server, path, body, timeout=600):
+            if path == "/admin/ec/rebuild_partial":
+                body = dict(body, chunk=chunk)
+            return post(server, path, body, timeout=timeout)
+
+        monkeypatch.setattr(env, "vs_post", small_chunks)
+        before = {s: count(s) for s in steps}
+        remote0 = read_bytes("ec_repair_read_bytes_by_code_total",
+                             code.spec)
+        local0 = read_bytes("ec_repair_local_read_bytes_total", code.spec)
+        out = commands_ec.ec_rebuild(env, vid)
+        assert out["mode"] == "partial" and out["rebuilt"] == [1]
+        shard_size = out["rebuilt_bytes"]
+        chunks = -(-shard_size // chunk)
+        assert chunks > 1
+        for s in steps[1:]:
+            assert count(s) - before[s] == chunks, s
+        # the rebuilder holds some of the group, never all of it
+        assert count(steps[0]) > before[steps[0]]
+        remote = read_bytes("ec_repair_read_bytes_by_code_total",
+                            code.spec) - remote0
+        local = read_bytes("ec_repair_local_read_bytes_total",
+                           code.spec) - local0
+        assert local > 0 and remote > 0
+        assert local + remote == len(plan.reads) * shard_size
+        # the ring: the rebuild's phases, no per-chunk step
+        root = [s for s in tracing._spans if s["name"] == "ec.rebuild"][-1]
+        internal = [s["name"] for s in tracing._spans
+                    if s["trace_id"] == root["trace_id"]
+                    and s["kind"] == "internal"]
+        assert sorted(internal) == sorted(
+            ["ec.rebuild", "ec.rebuild.plan", "ec.rebuild.partial",
+             "ec.rebuild.wait_registration"])
+        # the healed volume still serves every object
+        locs = env.ec_shard_locations(vid)
+        fid, data = next(iter(payloads.items()))
+        assert requests.get(f"http://{locs[1][0]}/{fid}").content == data
 
     def test_partial_rebuild_rejects_garbage(self, cluster, env,
                                              sealed_volume):

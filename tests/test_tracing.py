@@ -119,6 +119,67 @@ class TestSpans:
         assert not lines
 
 
+def _span_count(name: str) -> float:
+    return metrics._counters.get(
+        ("span_seconds_count", (("name", name),)), 0.0)
+
+
+class TestPhasesAndIntervals:
+    def test_internal_span_annotates_profiler_trace(self, trace_config,
+                                                    tmp_path):
+        import jax
+        from jax.profiler import ProfileData
+
+        before = _span_count("test.phase")
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tracing.span("test.phase"):
+                with tracing.interval("test.step"):
+                    time.sleep(0.002)
+            # a server span opens no annotation
+            with tracing.span("test.server", service="t", kind="server"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        path = next(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+        names = {e.name for plane in ProfileData.from_file(str(path)).planes
+                 for line in plane.lines for e in line.events}
+        assert {"swfs.test.phase", "swfs.test.step"} <= names
+        assert "swfs.test.server" not in names
+        assert _span_count("test.phase") == before + 1
+        assert _span_count("test.server") == 0
+
+    def test_interval_observes_and_adds_no_ring_record(self, trace_config):
+        tracing.reset()
+        before = _span_count("test.chunk")
+        with tracing.span("test.job"):
+            for _ in range(5):
+                with tracing.interval("test.chunk") as iv:
+                    time.sleep(0.001)
+                assert iv.seconds >= 0.001
+            with tracing.interval("test.quiet", observe=False):
+                pass
+        assert [s["name"] for s in tracing._spans] == ["test.job"]
+        assert _span_count("test.chunk") == before + 5
+        assert _span_count("test.quiet") == 0
+        assert 'span_seconds_count{name="test.chunk"}' in metrics.render()
+
+    def test_importing_tracing_does_not_import_jax(self):
+        import subprocess
+        import sys
+
+        code = ("import sys\n"
+                "from seaweedfs_tpu.utils import tracing\n"
+                "with tracing.span('p'):\n"
+                "    with tracing.interval('i'):\n"
+                "        pass\n"
+                "assert not any(m == 'jax' or m.startswith('jax.')\n"
+                "               for m in sys.modules), 'jax imported'\n")
+        out = subprocess.run([sys.executable, "-c", code],
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr
+
+
 class TestClusterPropagation:
     def test_one_trace_spans_s3_filer_volume(self, tmp_path_factory,
                                              trace_config):

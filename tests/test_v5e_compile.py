@@ -66,7 +66,10 @@ def test_pallas_kernel(one_chip, spec):
         _spec((8 * m, 8 * k), jnp.bfloat16, one_chip),
         _spec((m, 8 * m), jnp.bfloat16, one_chip),
         _spec((k, PALLAS_COLS), jnp.uint8, one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the op carries the program's own name, which a device trace shows
+    assert f"%{codec_pallas.KERNEL_NAME}." in text
 
 
 def test_xla_dense_kernel(one_chip):
