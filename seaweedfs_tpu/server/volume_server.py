@@ -2322,6 +2322,7 @@ class VolumeServer:
                                   deadline_t: float,
                                   bps: float = 0.0) -> bytes | None:
         import requests
+        import urllib3
 
         from ..rpc.httpclient import session
 
@@ -2334,13 +2335,23 @@ class VolumeServer:
             if bps > 0:  # repair pull: let the source shape its side
                 params["bps"] = bps
             try:
-                r = session().get(
-                    f"http://{holder}/admin/ec/shard_read",
-                    params=params,
-                    timeout=min(remaining, 10.0))
-                if r.status_code == 200:
-                    return r.content
-            except requests.RequestException:
+                with session().get(
+                        f"http://{holder}/admin/ec/shard_read",
+                        params=params, stream=True,
+                        timeout=min(remaining, 10.0)) as r:
+                    if r.status_code != 200:
+                        continue
+                    # the whole body in one read: `.content` pulls it
+                    # 10 KiB at a time through urllib3's Python read
+                    # path, ~400 trips per 4 MiB range, each contending
+                    # for the GIL with every server loop of an
+                    # in-process cluster. A body shorter than its
+                    # Content-Length raises (the next holder is tried,
+                    # never a short row); a full read hands the
+                    # connection back to the pool.
+                    return r.raw.read(decode_content=True)
+            except (requests.RequestException,
+                    urllib3.exceptions.HTTPError):
                 continue
         return None
 

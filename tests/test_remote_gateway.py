@@ -58,8 +58,10 @@ def test_bucket_create_mirrors_and_mounts(cluster, gateway):
                   params={"mkdir": "1"}).raise_for_status()
     _wait(lambda: os.path.isdir(os.path.join(cloud, "media")),
           msg="remote bucket dir")
+    # the gateway makes the remote directory, then saves the conf
+    _wait(lambda: "/buckets/media" in load_conf(cluster.filer_url).mounts,
+          msg="mount")
     conf = load_conf(cluster.filer_url)
-    assert "/buckets/media" in conf.mounts
     assert conf.mounts["/buckets/media"].remote_path == "media"
 
 
@@ -94,8 +96,9 @@ def test_bucket_delete_removes_remote_and_mount(cluster, gateway):
                     params={"recursive": "true"}).raise_for_status()
     _wait(lambda: not os.path.isdir(os.path.join(cloud, "scratch")),
           msg="remote bucket removal")
-    conf = load_conf(cluster.filer_url)
-    assert "/buckets/scratch" not in conf.mounts
+    # the gateway removes the remote directory, then saves the conf
+    _wait(lambda: "/buckets/scratch" not in
+          load_conf(cluster.filer_url).mounts, msg="mount removal")
 
 
 def test_include_exclude_filters():

@@ -12,6 +12,31 @@ from seaweedfs_tpu.shell import commands_ec, commands_volume
 from seaweedfs_tpu.shell.env import CommandEnv, ShellError
 
 
+def _until(fn, what):
+    """The master learns of a shard's loss or return from the next
+    heartbeat: poll what it lists."""
+    import time
+
+    deadline = time.monotonic() + 20
+    while not fn():
+        assert time.monotonic() < deadline, what
+        time.sleep(0.05)
+
+
+def _drop_shards(env, vid, sids):
+    """Delete `sids` everywhere, then wait for the master's listing: a
+    rebuild planned or a listing read before it sees the loss still
+    finds the shards."""
+    locs = env.ec_shard_locations(vid)
+    for sid in sids:
+        for url in locs.get(sid, []):
+            env.vs_post(url, "/admin/ec/delete",
+                        {"volume": vid, "shard_ids": [sid]})
+    _until(lambda: not any(env.ec_shard_locations(vid).get(s)
+                           for s in sids),
+           f"shards {sids} still listed")
+
+
 @pytest.fixture(scope="module")
 def cluster(tmp_path_factory):
     c = Cluster(str(tmp_path_factory.mktemp("ec_cluster")),
@@ -73,12 +98,8 @@ class TestEcEncode:
             self, cluster, env, sealed_volume):
         vid, payloads = sealed_volume
         commands_ec.ec_encode(env, vid)
-        locs = env.ec_shard_locations(vid)
         # delete 2 data shards + 2 parity shards (max tolerable)
-        for sid in (1, 4, 10, 13):
-            for url in locs.get(sid, []):
-                env.vs_post(url, "/admin/ec/delete",
-                            {"volume": vid, "shard_ids": [sid]})
+        _drop_shards(env, vid, (1, 4, 10, 13))
         locs2 = env.ec_shard_locations(vid)
         remaining = {sid for sid, urls in locs2.items() if urls}
         assert len(remaining) == 10
@@ -91,11 +112,7 @@ class TestEcEncode:
     def test_rebuild_restores_full_set(self, cluster, env, sealed_volume):
         vid, payloads = sealed_volume
         commands_ec.ec_encode(env, vid)
-        locs = env.ec_shard_locations(vid)
-        for sid in (0, 7, 12):
-            for url in locs.get(sid, []):
-                env.vs_post(url, "/admin/ec/delete",
-                            {"volume": vid, "shard_ids": [sid]})
+        _drop_shards(env, vid, (0, 7, 12))
         result = commands_ec.ec_rebuild(env, vid)
         assert sorted(result["rebuilt"]) == [0, 7, 12]
         locs2 = env.ec_shard_locations(vid)
@@ -140,32 +157,12 @@ class TestPartialRepairTraffic:
         return metrics._counters.get(
             ("repair_read_bytes_total", (("mode", mode),)), 0.0)
 
-    @staticmethod
-    def _until(fn, what):
-        """The master learns of a shard's loss or return from the next
-        heartbeat: poll what it lists."""
-        import time
-
-        deadline = time.monotonic() + 20
-        while not fn():
-            assert time.monotonic() < deadline, what
-            time.sleep(0.05)
-
-    def _drop_shard(self, env, vid, sid):
-        for url in env.ec_shard_locations(vid).get(sid, []):
-            env.vs_post(url, "/admin/ec/delete",
-                        {"volume": vid, "shard_ids": [sid]})
-        # a rebuild planned before the master sees the loss finds
-        # nothing missing
-        self._until(lambda: not env.ec_shard_locations(vid).get(sid),
-                    f"shard {sid} still listed")
-
     def test_partial_moves_fewer_bytes_than_full(self, cluster, env,
                                                  sealed_volume):
         vid, payloads = sealed_volume
         commands_ec.ec_encode(env, vid)
         # leg 1: lose shard 3, repair through the partial path
-        self._drop_shard(env, vid, 3)
+        _drop_shards(env, vid, (3,))
         p0, f0 = self._read_bytes("partial"), self._read_bytes("full")
         out = commands_ec.ec_rebuild(env, vid, partial=True)
         assert out["mode"] == "partial"
@@ -176,7 +173,7 @@ class TestPartialRepairTraffic:
         assert self._read_bytes("full") == f0, \
             "partial repair leaked full-path traffic"
         # leg 2: the SAME single-shard loss repaired the classic way
-        self._drop_shard(env, vid, 3)
+        _drop_shards(env, vid, (3,))
         f1 = self._read_bytes("full")
         out2 = commands_ec.ec_rebuild(env, vid, partial=False)
         assert out2["mode"] == "full"
@@ -208,7 +205,7 @@ class TestPartialRepairTraffic:
                       if f"{s.store.ip}:{s.store.port}" == locs[1][0])
         shard = holder.store.ec_volumes[vid].shards[1]
         golden = shard.read_at(0, shard.size)
-        self._drop_shard(env, vid, 1)
+        _drop_shards(env, vid, (1,))
         plan = reg_code.repair_plan(
             [1], [s for s in range(reg_code.total) if s != 1])
         assert plan is not None and plan.kind == "local"
@@ -250,8 +247,8 @@ class TestPartialRepairTraffic:
         healed = rebuilder.store.ec_volumes[vid].shards[1]
         assert healed.read_at(0, healed.size) == golden
         # the healed volume still serves reads
-        self._until(lambda: env.ec_shard_locations(vid).get(1),
-                    "rebuilt shard 1 never listed")
+        _until(lambda: env.ec_shard_locations(vid).get(1),
+               "rebuilt shard 1 never listed")
         locs2 = env.ec_shard_locations(vid)
         fid, data = next(iter(payloads.items()))
         assert requests.get(
@@ -281,7 +278,7 @@ class TestPartialRepairTraffic:
         vid, payloads = sealed_volume
         commands_ec.ec_encode(env, vid, codec="lrc-10.2.2")
         _col, code, _locs = env.ec_full_info(vid)
-        self._drop_shard(env, vid, 1)
+        _drop_shards(env, vid, (1,))
         plan = code.repair_plan(
             [1], [s for s in range(code.total) if s != 1])
         assert plan is not None and plan.kind == "local"
