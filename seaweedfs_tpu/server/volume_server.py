@@ -15,10 +15,13 @@ writes.
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import contextvars
 import json
 import os
+import threading
 import time
+from concurrent.futures import Future, ThreadPoolExecutor
 
 import aiohttp
 from aiohttp import web
@@ -81,6 +84,35 @@ class InFlightLimiter:
             cond = self._c()
             async with cond:
                 cond.notify_all()
+
+
+class _FetchPool(ThreadPoolExecutor):
+    """The shard-range fan-out's workers: one for each shard of the
+    widest code the geometry admits, so every candidate of a fan-out
+    is in flight at once. Tracks the ranges in flight to tell which
+    submissions found every worker busy and wait for one."""
+
+    def __init__(self):
+        super().__init__(max_workers=geo.MAX_SHARD_COUNT,
+                         thread_name_prefix="ec-fetch")
+        self._count_lock = threading.Lock()
+        self._in_flight = 0
+
+    def submit_range(self, fn, /, *args) -> tuple[Future, bool]:
+        """(future, whether it was queued behind busy workers). Runs
+        `fn` in the caller's context: pool.submit (unlike
+        asyncio.to_thread) drops contextvars, which would orphan the
+        fetch spans from the request trace and lose the deadline."""
+        with self._count_lock:
+            queued = self._in_flight >= self._max_workers
+            self._in_flight += 1
+        fut = self.submit(contextvars.copy_context().run, fn, *args)
+        fut.add_done_callback(self._finished)
+        return fut, queued
+
+    def _finished(self, _fut: Future) -> None:
+        with self._count_lock:
+            self._in_flight -= 1
 
 
 class VolumeServer:
@@ -2369,43 +2401,51 @@ class VolumeServer:
                                   deadline: float,
                                   bps: float = 0.0) -> dict:
         """Concurrent first-k-wins shard-range fan-out for degraded
-        reads (goroutine fan-out in store_ec.go:349-393): every
-        candidate shard is requested at once; the call returns as soon
-        as `need` of them arrive or the deadline passes, so one hung
-        peer costs nothing but its own thread."""
+        reads and partial rebuilds (goroutine fan-out in
+        store_ec.go:349-393): every candidate shard is requested at
+        once, the pool holding a worker for each shard of the widest
+        code (_FetchPool); the call returns as soon as `need` of them
+        arrive or the deadline passes, so one hung peer costs nothing
+        but its own thread. Counts the ranges submitted and those
+        queued behind busy workers, and times `ec.fetch.tail` from the
+        first range's arrival to the return: where `need` is every
+        candidate, the wait on the slowest holder."""
         from concurrent.futures import FIRST_COMPLETED, wait
 
         me = f"{self.store.ip}:{self.store.port}"
         holders_map = self._ec_holders(vid)
         deadline_t = time.monotonic() + deadline
         pool = getattr(self, "_ec_fetch_pool", None)
-        if pool is None:
-            from concurrent.futures import ThreadPoolExecutor
-
-            pool = self._ec_fetch_pool = ThreadPoolExecutor(
-                max_workers=16, thread_name_prefix="ec-fetch")
+        if pool is None:  # setdefault: concurrent fan-outs share one
+            pool = self.__dict__.setdefault("_ec_fetch_pool", _FetchPool())
         futs = {}
+        queued = 0
         for sid in sids:
             holders = [h for h in holders_map.get(str(sid), []) if h != me]
             if holders:
-                # copy_context: pool.submit (unlike asyncio.to_thread)
-                # drops contextvars, which would orphan the fetch spans
-                # from the request trace and lose the deadline
-                futs[pool.submit(
-                    contextvars.copy_context().run,
+                fut, waits = pool.submit_range(
                     self._fetch_shard_from_holders, vid, sid, holders,
-                    offset, size, deadline_t, bps)] = sid
+                    offset, size, deadline_t, bps)
+                futs[fut] = sid
+                queued += waits
+        metrics.counter_add("ec_fetch_fanout_ranges_total", len(futs))
+        metrics.counter_add("ec_fetch_fanout_queued_total", queued)
         out: dict[int, bytes] = {}
         pending = set(futs)
-        while pending and len(out) < need:
-            remaining = deadline_t - time.monotonic()
-            if remaining <= 0:
-                break
-            done, pending = wait(pending, timeout=remaining,
-                                 return_when=FIRST_COMPLETED)
-            for fut in done:
-                data = fut.result()
-                if data is not None:
+        with contextlib.ExitStack() as tail:
+            while pending and len(out) < need:
+                remaining = deadline_t - time.monotonic()
+                if remaining <= 0:
+                    break
+                done, pending = wait(pending, timeout=remaining,
+                                     return_when=FIRST_COMPLETED)
+                for fut in done:
+                    data = fut.result()
+                    if data is None:
+                        continue
+                    if not out:
+                        tail.enter_context(
+                            tracing.interval("ec.fetch.tail"))
                     out[futs[fut]] = data
         for fut in pending:  # abandoned losers; bounded by timeouts
             fut.cancel()

@@ -9,6 +9,7 @@ agrees.
 """
 import os
 import secrets
+import time
 
 import numpy as np
 import pytest
@@ -23,6 +24,7 @@ from seaweedfs_tpu.server.cluster import Cluster
 from seaweedfs_tpu.shell import commands_ec
 from seaweedfs_tpu.shell.env import CommandEnv
 from seaweedfs_tpu.shell.repl import run_command
+from seaweedfs_tpu.utils import metrics
 
 
 # ---------------------------------------------------------------------
@@ -174,6 +176,85 @@ def test_wide_encode_spread_degraded_read(cluster):
         assert commands_ec.ec_verify(env, vid)["verified"]
     finally:
         env.close()
+
+
+# ---------------------------------------------------------------------
+# eight failure domains: a whole server lost, rebuilt where it was
+# ---------------------------------------------------------------------
+
+def _counter(name):
+    return metrics._counters.get((name, ()), 0.0)
+
+
+def test_wide_server_loss_rebuilt_on_the_server_that_lost_it(tmp_path):
+    # 8 servers in 8 racks: ceil(32/4) failure domains, 4 shards each
+    cluster = Cluster(str(tmp_path), n_volume_servers=8,
+                      volume_size_limit=64 << 20, max_volumes=8,
+                      topology=[("dc1", f"rack{i}") for i in range(8)])
+    env = CommandEnv(cluster.master_url)
+    env.acquire_lock()
+    try:
+        # 30 MiB of needles: more than one 28 x 1 MiB stripe row, so
+        # every data shard holds data
+        rng = np.random.default_rng(25)
+        a = verbs.assign(cluster.master_url, count=30, collection="cold")
+        vid = int(a.fid.split(",")[0])
+        for i in range(30):
+            fid = a.fid if i == 0 else f"{a.fid}_{i}"
+            verbs.upload(f"http://{a.url}/{fid}", rng.bytes(1 << 20))
+        run_command(env, f"ec.encode -volumeId={vid} -codec=28.4")
+
+        def shard_paths():
+            out = {}
+            for sid, urls in env.ec_full_info(vid)[2].items():
+                store = next(s for s in cluster.stores
+                             if s.public_url == urls[0])
+                out[sid] = (urls[0],
+                            store.ec_volumes[vid].shards[sid].path)
+            return out
+
+        before = shard_paths()
+        by_url: dict[str, list[int]] = {}
+        for sid, (url, _) in before.items():
+            by_url.setdefault(url, []).append(sid)
+        assert sorted(len(v) for v in by_url.values()) == [4] * 8
+        dead = before[0][0]
+        lost = sorted(by_url[dead])
+        golden = {sid: open(before[sid][1], "rb").read() for sid in lost}
+        shard_size = len(golden[lost[0]])
+
+        env.vs_post(dead, "/admin/ec/delete",
+                    {"volume": vid, "shard_ids": lost})
+        deadline = time.monotonic() + 30
+        while set(lost) & set(env.ec_full_info(vid)[2]):
+            assert time.monotonic() < deadline, "the loss never showed"
+            time.sleep(0.05)
+
+        ranges0 = _counter("ec_fetch_fanout_ranges_total")
+        queued0 = _counter("ec_fetch_fanout_queued_total")
+        out = commands_ec.ec_rebuild(env, vid)
+        assert out["mode"] == "partial"
+        assert out["rebuilder"] == dead
+        assert sorted(out["rebuilt"]) == lost
+
+        after = shard_paths()
+        assert all(after[sid][0] == dead for sid in lost)
+        for sid in lost:
+            assert open(after[sid][1], "rb").read() == golden[sid]
+        # the whole 32-shard set is an RS(28,4) codeword of the numpy
+        # oracle codec
+        stack = np.stack([np.fromfile(after[sid][1], dtype=np.uint8)
+                          for sid in range(32)])
+        assert ReedSolomon(28, 4, backend="numpy").verify(stack)
+        # the rebuilder holds none of the volume's shards: each 4 MiB
+        # chunk asks all 28 survivors at once, none waiting for a worker
+        chunks = -(-shard_size // (4 << 20))
+        assert _counter("ec_fetch_fanout_ranges_total") - ranges0 == \
+            28 * chunks
+        assert _counter("ec_fetch_fanout_queued_total") - queued0 == 0
+    finally:
+        env.close()
+        cluster.stop()
 
 
 def test_reencode_default_clears_stale_codec(tmp_path):

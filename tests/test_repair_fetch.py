@@ -2,15 +2,21 @@
 (VolumeServer._fetch_shard_from_holders), against live holder stubs:
 a sized body and a chunked one come back byte-identical from the
 one-call read, a short body fails its holder over to the next, and
-keep-alive survives the read."""
+keep-alive survives the read; and the first-k-wins fan-out over them
+(VolumeServer._remote_shards_fetch_sync) has every range of the
+widest code in flight at once."""
+import asyncio
 import time
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
 from aiohttp import web
 
+from seaweedfs_tpu.ec import geometry as geo
 from seaweedfs_tpu.rpc.http import ServerThread
 from seaweedfs_tpu.server.volume_server import VolumeServer
+from seaweedfs_tpu.utils import metrics
 
 RANGE = 4 << 20
 DATA = np.random.default_rng(24).bytes(2 * RANGE)
@@ -116,3 +122,50 @@ def test_sequential_fetches_reuse_one_connection(holders):
     assert _fetch([h], offset=RANGE) == DATA[RANGE:]
     assert len(h.peers) == 2
     assert h.peers[0] == h.peers[1], "the second fetch dialled anew"
+
+
+def test_fanout_of_widest_code_is_all_in_flight(holders):
+    # each range answers only once every shard of the widest code has
+    # asked (or after 5 s): a pool with fewer workers than shards
+    # leaves the rest queued, and the peak stays at its worker count
+    n = geo.MAX_SHARD_COUNT
+    seen = {"now": 0, "peak": 0, "all_in": None}
+
+    async def gated(req):
+        if seen["all_in"] is None:
+            seen["all_in"] = asyncio.Event()
+        seen["now"] += 1
+        seen["peak"] = max(seen["peak"], seen["now"])
+        if seen["now"] == n:
+            seen["all_in"].set()
+        try:
+            await asyncio.wait_for(seen["all_in"].wait(), 5)
+        except asyncio.TimeoutError:
+            pass
+        return await _whole(req)
+
+    h = holders(gated)
+    vs = VolumeServer.__new__(VolumeServer)
+    vs.store = SimpleNamespace(ip="127.0.0.1", port=0)
+    vs._ec_holders = lambda vid: {str(s): [h.thread.address]
+                                  for s in range(n)}
+
+    def counter(name):
+        return metrics._counters.get((name, ()), 0.0)
+
+    ranges0 = counter("ec_fetch_fanout_ranges_total")
+    queued0 = counter("ec_fetch_fanout_queued_total")
+    size = 64 << 10
+    try:
+        t0 = time.monotonic()
+        got = vs._remote_shards_fetch_sync(7, list(range(n)), size, size,
+                                           need=n, deadline=30)
+        took = time.monotonic() - t0
+    finally:
+        vs._ec_fetch_pool.shutdown(wait=True)
+    assert sorted(got) == list(range(n))
+    assert all(v == DATA[size:2 * size] for v in got.values())
+    assert seen["peak"] == n
+    assert took < 5, "ranges waited for a worker"
+    assert counter("ec_fetch_fanout_ranges_total") - ranges0 == n
+    assert counter("ec_fetch_fanout_queued_total") - queued0 == 0

@@ -397,10 +397,13 @@ class TestSpanPusher:
                 pass
             tid = rec["trace_id"]
             # the master's in-process sink sees the span immediately;
-            # wait for the HTTP push specifically
+            # wait for the HTTP push specifically; the pusher counts a
+            # batch only once the master's answer is back, after the
+            # master has listed it
             deadline = time.time() + 10
             while time.time() < deadline:
-                if "unit:1" in m.collector.observability()["Pushers"]:
+                if ("unit:1" in m.collector.observability()["Pushers"]
+                        and _counter("trace_spans_pushed_total") > pushed0):
                     break
                 time.sleep(0.05)
             assert m.collector.get_trace(tid) is not None
