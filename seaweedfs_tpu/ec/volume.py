@@ -67,8 +67,19 @@ class EcVolumeShard:
         self.size = os.path.getsize(self.path)
 
     def read_at(self, offset: int, size: int) -> bytes:
-        self._f.seek(offset)
-        return self._f.read(size)
+        """`size` bytes from `offset`, fewer only at the end of the
+        file. A positional read: concurrent readers of one shard share
+        the descriptor but never a file offset."""
+        fd = self._f.fileno()
+        parts = []
+        got = 0
+        while got < size:
+            part = os.pread(fd, size - got, offset + got)
+            if not part:  # end of file
+                break
+            parts.append(part)
+            got += len(part)
+        return b"".join(parts)
 
     def close(self) -> None:
         self._f.close()

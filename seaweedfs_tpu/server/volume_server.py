@@ -1654,7 +1654,8 @@ class VolumeServer:
         every surviving shard file (full stripe, the ec/copy +
         ec/rebuild path), stream only the k shard ranges the codec
         needs through the degraded-read guard's first-k-wins fan-out
-        and rebuild the missing shard(s) chunk by chunk — the
+        and rebuild the missing shard(s) chunk by chunk, each chunk
+        gathered while the one before it is reconstructed — the
         partial-stripe repair the warehouse study (arXiv 1309.0186)
         motivates. Bytes fetched are accounted as
         repair_read_bytes_total{mode="partial"} (the classic path
@@ -1898,23 +1899,45 @@ class VolumeServer:
                     f"ranges at +{off}")
             return rows
 
+        def gather(off: int, n: int) -> dict:
+            rows = gather_planned(off, n) if plan is not None else None
+            return gather_generic(off, n) if rows is None else rows
+
+        # a one-chunk lookahead: the gather of chunk i+1 (local reads
+        # and the fan-out: network and socket work) runs on one worker
+        # while chunk i is reconstructed and written here, so at most
+        # two chunks are in hand. Gathers run one at a time on that
+        # worker, the only thread touching the plan and the byte
+        # counters; leaving the block joins it, so no gather outlives
+        # the rebuild and the counters are final below.
+        ranges = [(off, min(chunk, shard_size - off))
+                  for off in range(0, shard_size, chunk)]
         written = 0
         files = {s: open(base + geo.shard_ext(s), "wb")
                  for s in missing}
         try:
-            for off in range(0, shard_size, chunk):
-                n = min(chunk, shard_size - off)
-                rows = gather_planned(off, n) if plan is not None \
-                    else None
-                if rows is None:
-                    rows = gather_generic(off, n)
-                with tracing.interval("ec.rebuild.reconstruct"):
-                    rec = rs.reconstruct(rows, missing=missing)
-                with tracing.interval("ec.rebuild.write"):
-                    for s in missing:
-                        row = np.asarray(rec[s], dtype=np.uint8).tobytes()
-                        files[s].write(row)
-                        written += len(row)
+            with ThreadPoolExecutor(
+                    1, thread_name_prefix="ec-rebuild-gather") as ahead:
+
+                def gather_ahead(i: int) -> Future:
+                    return ahead.submit(contextvars.copy_context().run,
+                                        gather, *ranges[i])
+
+                pending = gather_ahead(0)
+                for i in range(len(ranges)):
+                    # the part of the gather the lookahead did not hide
+                    with tracing.interval("ec.rebuild.gather_wait"):
+                        rows = pending.result()
+                    if i + 1 < len(ranges):
+                        pending = gather_ahead(i + 1)
+                    with tracing.interval("ec.rebuild.reconstruct"):
+                        rec = rs.reconstruct(rows, missing=missing)
+                    with tracing.interval("ec.rebuild.write"):
+                        for s in missing:
+                            row = np.asarray(rec[s],
+                                             dtype=np.uint8).tobytes()
+                            files[s].write(row)
+                            written += len(row)
         except Exception:
             for s, f in files.items():
                 f.close()
