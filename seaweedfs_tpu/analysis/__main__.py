@@ -1,7 +1,7 @@
 """CLI: ``python -m seaweedfs_tpu.analysis [roots...]``.
 
 Exit code 1 when any unsuppressed, non-baselined finding remains —
-wired into ``pytest -m lint`` and the ``bench.py lint-time`` gate.
+wired into ``pytest -m lint``.
 """
 from __future__ import annotations
 

@@ -7,7 +7,8 @@ stalls a request thread behind the host<->device link for the
 whole transfer, and an argless ``device_put(x)`` uploads to an
 UNCOMMITTED default device — XLA is then free to re-copy the array per
 executable. All device traffic belongs in the staged pipeline
-(ops/codec_jax.py) behind the measured router (ec/backend.py).
+(ops/codec_pallas.py, ops/feed.py) behind the measured router
+(ec/backend.py).
 """
 from __future__ import annotations
 

@@ -8,11 +8,12 @@ Two contracts:
    raise ``ConcretizationTypeError`` at runtime or, worse, silently
    constant-fold a value that should be data-dependent.
 
-2. In the pipelined feed modules (ops/codec_jax.py, ops/codec_mesh.py,
-   models/ec_pipeline.py, ec/probe.py) the double-buffered overlap is
-   the whole point: a stray ``block_until_ready``/``device_get`` on
-   the submit path re-serialises upload and compute and the measured
-   H2D/kernel overlap collapses. Sync primitives are allowed only in
+2. In the pipelined feed modules (ops/codec_pallas.py, ops/feed.py,
+   ops/codec_mesh.py, models/ec_pipeline.py, ec/probe.py) the
+   double-buffered overlap is the whole point: a stray
+   ``block_until_ready``/``device_get`` on the submit path
+   re-serialises upload and compute and the measured H2D/kernel
+   overlap collapses. Sync primitives are allowed only in
    the named drain-site functions below (the upload/drain workers and
    host readbacks, where blocking IS the contract).
 """
@@ -23,19 +24,17 @@ import ast
 from ..engine import PKG_PREFIX, Rule, register
 
 FEED_MODULES = (
-    "ops/codec_jax.py",
+    "ops/codec_pallas.py",
+    "ops/feed.py",
     "ops/codec_mesh.py",
     "models/ec_pipeline.py",
     "ec/probe.py",
 )
 
 # drain sites: functions whose contract is "block here" — the staged
-# feed's upload/drain workers, the host readback helpers, and the
-# scheduled-vs-dense measurement probes (run_sched/run_dense time one
-# synchronous kernel each so the chooser compares wall clock, never
-# called on the streaming submit path)
+# feed's upload/drain workers and the host readback helpers
 ALLOWED_SYNC_FUNCS = {"upload", "drain", "finish", "up", "down",
-                      "_readback", "_collect", "run_sched", "run_dense"}
+                      "_readback", "_collect"}
 
 
 def _is_jitted(func: ast.AST) -> bool:

@@ -54,6 +54,18 @@ def _add_commit_flags(p) -> None:
              "before -commit.maxDelay elapses (default 4MiB)")
 
 
+def _add_ec_backend_flag(p) -> None:
+    """-ec.backend, shared by the volume and combined-server commands;
+    a name the registry does not know fails at parse time."""
+    from .ec.backend import backend_names
+
+    p.add_argument("-ec.backend", dest="ec_backend", default="auto",
+                   choices=backend_names(),
+                   help="erasure-coding codec: auto (measured-curve "
+                        "router) | native | numpy | pallas | "
+                        "mesh (all local devices)")
+
+
 def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     """The command line, parsed: every subcommand and its flags."""
     parser = argparse.ArgumentParser(
@@ -387,10 +399,7 @@ def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     p.add_argument("-mserver", default="127.0.0.1:9333")
     p.add_argument("-dataCenter", default="DefaultDataCenter")
     p.add_argument("-rack", default="DefaultRack")
-    p.add_argument("-ec.backend", dest="ec_backend", default="auto",
-                   help="erasure-coding codec: auto (measured-curve "
-                        "router) | native | numpy | jax | pallas | "
-                        "mesh (all local devices)")
+    _add_ec_backend_flag(p)
     p.add_argument("-ec.code", dest="ec_code", default="",
                    help="erasure-code family new EC volumes are "
                         "encoded with: 10.4 (RS default) | 28.4 "
@@ -481,10 +490,7 @@ def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
                         "-filer.cache.entries is set, else off")
     p.add_argument("-ip", default="127.0.0.1")
     p.add_argument("-volumeSizeLimitMB", type=int, default=1024)
-    p.add_argument("-ec.backend", dest="ec_backend", default="auto",
-                   help="erasure-coding codec: auto (measured-curve "
-                        "router) | native | numpy | jax | pallas | "
-                        "mesh (all local devices)")
+    _add_ec_backend_flag(p)
     p.add_argument("-ec.code", dest="ec_code", default="",
                    help="erasure-code family new EC volumes are "
                         "encoded with: 10.4 (RS default) | 28.4 "

@@ -3,7 +3,7 @@
 Mirrors the reference's storage-backend plugin pattern — a factory registry
 keyed by a type string (/root/reference/weed/storage/backend/backend.go:
 25-45 `BackendStorageFactory` / `BackendStorages`) — applied to the RS
-codec, selected via config `ec.backend=numpy|jax|native|pallas` (the
+codec, selected via config `ec.backend=numpy|native|pallas|mesh` (the
 north-star `-ec.backend=tpu` switch from BASELINE.json).
 
 A backend implements one method:
@@ -67,7 +67,7 @@ class CodecBackend(Protocol):
 
 # the codecs that run on the accelerator (and so need the process
 # that owns it)
-DEVICE_BACKENDS = ("pallas", "jax", "mesh")
+DEVICE_BACKENDS = ("pallas", "mesh")
 
 _factories: dict[str, Callable[[], CodecBackend]] = {}
 _instances: dict[str, CodecBackend] = {}
@@ -105,7 +105,7 @@ def available_backend_names() -> list[str]:
     lookup), without constructing instances or importing jax."""
     import importlib.util
 
-    deps = {"numpy": "numpy", "jax": "jax", "mesh": "jax",
+    deps = {"numpy": "numpy", "mesh": "jax",
             "pallas": "seaweedfs_tpu.ops.codec_pallas",
             "native": "seaweedfs_tpu.ops.codec_native"}
     out = []
@@ -120,14 +120,6 @@ def _register_builtins() -> None:
     from ..ops import codec_numpy
 
     register("numpy", codec_numpy.NumpyCodec)
-
-    def _jax_factory():
-        from ..ops import codec_jax, device
-
-        device.require_accelerator("jax")
-        return codec_jax.JaxCodec()
-
-    register("jax", _jax_factory)
 
     def _native_factory():
         from ..ops import codec_native

@@ -7,7 +7,7 @@ coding: throughput is decided by data-movement scheduling, so the only
 honest router input is the *measured end-to-end rate of the actual
 pipelined feed* at the sizes production requests come in. This module
 produces that: a size x depth sweep of the real streaming codec
-(ops/codec_jax pipeline — committed device_put upload thread, kernel,
+(ops/codec_pallas pipeline — committed device_put upload thread, kernel,
 drain thread), each row paired with a shaped transfer-only ceiling
 twin (same bytes over the link, codec replaced by a trivial slice), so
 a published device number always carries the link bound it ran under.
@@ -204,7 +204,7 @@ def _measure_e2e_row(codec, coef, size: int, depth: int,
     """Pipelined e2e MB/s at one (size, depth): n_blocks distinct
     (k, size/k) blocks through the staged streaming pipeline; rate is
     input bytes / wall from first pread to last yield. k/m default to
-    the production RS(10,4) shape; the mesh rows and wide-code bench
+    the production RS(10,4) shape; the mesh rows and per-code sweeps
     pass their own."""
     w = max(1, size // k)
     rng = np.random.default_rng(size ^ depth)
@@ -242,8 +242,7 @@ def _measure_xfer_ceiling(codec, size: int, depth: int,
     uint8 blocks cross H2D and an (m, w) slice crosses D2H through the
     same committed placement and the same depth-bounded overlap, but
     the kernel is a free row slice — what the link alone supports for
-    this traffic shape. The paired-ceiling protocol bench.py already
-    applies to file encode, extended to device rows."""
+    this traffic shape."""
     from collections import deque
     from concurrent.futures import ThreadPoolExecutor
 
@@ -315,17 +314,11 @@ def run_sweep(sizes=SWEEP_SIZES, depths=SWEEP_DEPTHS,
     if dev is None:
         return curve
 
-    # device backend preference mirrors the router: fused kernel first
-    codec = None
-    for name in ("pallas", "jax"):
-        try:
-            codec = ecb.get_backend(name)
-            curve["device_backend"] = name
-            break
-        except KeyError:
-            continue
-    if codec is None:
-        return _device_failed(curve, "no device codec backend importable")
+    try:
+        codec = ecb.get_backend("pallas")
+    except KeyError as e:
+        return _device_failed(curve, repr(e))
+    curve["device_backend"] = "pallas"
 
     try:
         # spin up the path (first device_put, executor machinery)

@@ -7,8 +7,8 @@ same idea turned into a first-class composite: `make_store("sharded",
 shards=N, child="leveldb", path=DIR)` routes every entry to one of N
 independent child engines, each in its own directory, so LSM memtable
 flushes and compactions parallelize and one hot bucket's churn can't
-stall reads against the rest of the namespace (BENCH_GATEWAY.json
-measured the grown single store paying ~2x with p99 ~114 ms).
+stall reads against the rest of the namespace (a grown single store
+was measured on a CPU VM paying ~2x, with read p99 ~114 ms).
 
 Routing — bucket/first-segment with a consistent-hash ring:
 - `/buckets/<bucket>/**` routes by `buckets/<bucket>`: every S3 bucket

@@ -11,8 +11,8 @@ after a mutation returns.
 
 Why it pays: the weedkv engine serializes reads against memtable
 flushes and compactions on one lock, so a grown store's LSM churn is
-exactly what the read p99 measures (~114 ms at the BENCH_GATEWAY.json
-geometry). A cache hit never touches the engine, and misses only pay
+exactly what the read p99 measures (~114 ms for a grown store on a
+CPU VM). A cache hit never touches the engine, and misses only pay
 once per key per invalidation.
 
 Two caches, both LRU-bounded:

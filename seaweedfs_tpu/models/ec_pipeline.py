@@ -80,8 +80,8 @@ def sharded_encode_scrub(mesh, k: int = 10, m: int = 4):
 #
 # The jitted step above is device-side only; at volume scale the feed
 # is the bottleneck. These entry points run the same depth-N staged
-# pipeline as ops.codec_jax.JaxCodec.coded_matmul_stream — block j+1's
-# H2D overlaps block j's kernel and block j-1's D2H — with the same
+# pipeline as ops.codec_pallas.PallasCodec.coded_matmul_stream — block
+# j+1's H2D overlaps block j's kernel and block j-1's D2H — with the same
 # per-stage ec_codec_stage_seconds observations, so Grafana attributes
 # batched-encode and scrub time to pread/h2d/kernel/d2h/relay exactly
 # like the codec path.
@@ -97,7 +97,7 @@ def _staged_feed(blocks, upload, drain, depth: int, backend: str):
     from collections import deque
     from concurrent.futures import ThreadPoolExecutor
 
-    from ..ops.codec_jax import observe_stage, stage
+    from ..ops.feed import observe_stage, stage
 
     up_ex = ThreadPoolExecutor(max_workers=1,
                                thread_name_prefix="ecfeed-h2d")
@@ -145,7 +145,7 @@ def pipelined_encode_stream(stripe_blocks, k: int = 10, m: int = 4,
 
     from jax.sharding import SingleDeviceSharding
 
-    from ..ops.codec_jax import _readback, stage
+    from ..ops.feed import _readback, stage
 
     if mesh is not None:
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -207,7 +207,7 @@ def pipelined_scrub(pair_blocks, k: int = 10, m: int = 4,
 
     from jax.sharding import SingleDeviceSharding
 
-    from ..ops.codec_jax import stage
+    from ..ops.feed import stage
 
     if mesh is not None:
         from ..parallel.mesh import pad_to_mesh

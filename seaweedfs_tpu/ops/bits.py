@@ -1,7 +1,7 @@
 """Shared jax bit-plane pack/unpack — the device-side counterpart of
 gf256.unpack_bits/pack_bits (numpy).
 
-Every TPU codec path (codec_jax, models.ec_pipeline, bench) MUST use
+Every XLA codec path (codec_mesh, models.ec_pipeline) MUST use
 these two functions: the codecs have to stay bit-identical for shard
 interoperability, and divergent hand-rolled copies of the shift/weights
 transform are exactly how they'd drift apart.

@@ -20,8 +20,8 @@ single-chip codec, so `ec.encode -codec=28.4` volumes ride the same
 compiled kernel shape and amortize the per-byte transfer cost over
 2.8x more data bytes per parity byte.
 
-The streaming entry point mirrors `JaxCodec.coded_matmul_stream`: a
-depth-N staged pipeline (upload thread committing the sharded
+The streaming entry point mirrors `PallasCodec.coded_matmul_stream`:
+a depth-N staged pipeline (upload thread committing the sharded
 device_put, kernel, drain thread gathering the result) with the same
 ec_codec_stage_seconds{stage,backend="mesh"} attribution.
 """
@@ -35,11 +35,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..utils import metrics
-from . import gf256, schedule
+from . import gf256
+from .feed import observe_stage, stage
 
 # Per-vol-segment column widths are padded up to power-of-two buckets
 # (>= this) so repeated uneven blocks share a handful of XLA compiles,
-# mirroring JaxCodec._pad_width.
+# the mesh's counterpart of PallasCodec's COL_TILE padding.
 BUCKET_MIN = 256
 
 
@@ -54,26 +55,6 @@ def _mesh_kernel(a_bits: jax.Array, stripes: jax.Array) -> jax.Array:
     acc = jnp.einsum("st,btn->bsn", a_bits, bits,
                      preferred_element_type=jnp.float32)
     return pack_bits_uint8(acc.astype(jnp.int32) & 1)
-
-
-def _mesh_sched_kernel(program, stripes: jax.Array) -> jax.Array:
-    """Scheduled twin of _mesh_kernel: the CSE-optimized XOR program
-    (ops/schedule.Program, static) over uint8 bit-planes, batched over
-    the vol axis. Elementwise per column, so it composes with the same
-    (vol, col) NamedSharding with no collectives."""
-    from .bits import pack_bits_uint8
-
-    shifts = jnp.arange(8, dtype=jnp.uint8)
-    bits = (stripes[:, :, None, :] >> shifts[None, None, :, None]) & 1
-    bits = bits.reshape(stripes.shape[0], stripes.shape[1] * 8,
-                        stripes.shape[2])               # (vol, 8k, w)
-    pool = [bits[:, i, :] for i in range(program.n_in)]
-    for _, a, b in program.ops:
-        pool.append(pool[a] ^ pool[b])
-    zero = jnp.zeros_like(pool[0])
-    rows = jnp.stack([pool[v] if v >= 0 else zero
-                      for v in program.outputs], axis=1)  # (vol, 8m, w)
-    return pack_bits_uint8(rows)
 
 
 class MeshCodec:
@@ -97,9 +78,6 @@ class MeshCodec:
         self._repl = pmesh.replicated(mesh)
         self._bitmats: "OrderedDict[bytes, jax.Array]" = OrderedDict()
         self._fn = None
-        self._fn_meas = None
-        self._sched_fns: "OrderedDict[object, object]" = OrderedDict()
-        self._chooser = schedule.Chooser()
         self._donate = mesh.devices.flat[0].platform != "cpu"
         metrics.gauge_set("ec_mesh_devices", self.n_devices)
         metrics.gauge_set("ec_mesh_vol", self.vol)
@@ -122,69 +100,6 @@ class MeshCodec:
                 out_shardings=self._data_sh,
                 donate_argnums=(1,) if self._donate else ())
         return self._fn
-
-    def _sched_step(self, plan):
-        """Compiled scheduled kernel for one program (static arg);
-        bounded cache — one entry per distinct coefficient matrix."""
-        fn = self._sched_fns.get(plan)
-        if fn is None:
-            fn = jax.jit(
-                _mesh_sched_kernel, static_argnums=(0,),
-                in_shardings=(self._data_sh,),
-                out_shardings=self._data_sh)
-            self._sched_fns[plan] = fn
-            if len(self._sched_fns) > self.BITMAT_CACHE_MAX:
-                self._sched_fns.popitem(last=False)
-        else:
-            self._sched_fns.move_to_end(plan)
-        return fn
-
-    def _plan_for(self, coef: np.ndarray, nbytes: int):
-        """Measured scheduled-vs-dense choice for this (matrix, size
-        bucket) — same protocol as JaxCodec._plan_for, against the
-        sharded kernels: the verdict is keyed by the sample's own byte
-        size and measured on a background thread, serving the dense
-        kernel until it lands."""
-        k = coef.shape[1]
-        w = self._seg_width(max(1, min(nbytes // max(1, k), 4 << 20)))
-        sample_bytes = min(nbytes, self.vol * k * w)
-        state: dict = {}
-
-        def prep():
-            if not state:
-                rng = np.random.default_rng(0)
-                batched = rng.integers(
-                    0, 256, (self.vol, k, w), dtype=np.uint8)
-                state["dev"] = self._h2d(batched)
-                state["mats"] = self._coef_bits(coef)
-                state["plan"] = schedule.plan_for(coef)
-
-        def run_sched():
-            prep()
-            self._sched_step(state["plan"])(
-                state["plan"], state["dev"]).block_until_ready()
-
-        # measurement must not donate the shared sample buffer
-        if self._fn_meas is None:
-            self._fn_meas = jax.jit(
-                _mesh_kernel,
-                in_shardings=(self._repl, self._data_sh),
-                out_shardings=self._data_sh)
-
-        def run_dense():
-            prep()
-            self._fn_meas(state["mats"],
-                          state["dev"]).block_until_ready()
-
-        if self._chooser.use_scheduled(coef, sample_bytes, run_sched,
-                                       run_dense, background=True):
-            return schedule.plan_for(coef)
-        return None
-
-    def _kernel_call(self, mats, plan, dev):
-        if plan is not None:
-            return self._sched_step(plan)(plan, dev)
-        return self._step()(mats, dev)
 
     def _coef_bits(self, coef: np.ndarray) -> jax.Array:
         key = coef.shape[0].to_bytes(2, "big") + coef.tobytes()
@@ -252,10 +167,9 @@ class MeshCodec:
         n = shards.shape[1]
         if n == 0:
             return np.zeros((m, 0), dtype=np.uint8)
-        plan = self._plan_for(coef, shards.nbytes)
         mats = self._coef_bits(coef)
         batched, _per = self._to_batched(shards)
-        out = self._kernel_call(mats, plan, self._h2d(batched))
+        out = self._step()(mats, self._h2d(batched))
         return self._from_batched(np.asarray(out), n)
 
     def coded_matmul_stream(self, coef: np.ndarray, blocks,
@@ -269,23 +183,18 @@ class MeshCodec:
         from collections import deque
         from concurrent.futures import Future, ThreadPoolExecutor
 
-        from .codec_jax import observe_stage, stage
-
         coef = np.asarray(coef, dtype=np.uint8)
         m = coef.shape[0]
         mats = self._coef_bits(coef)
         depth = max(1, int(depth))
         backend = self.name
-        # streams are bulk: one scheduled-vs-dense decision up front
-        plan = self._plan_for(
-            coef, coef.shape[1] * self.n_devices * (1 << 20))
 
         def upload(block: np.ndarray):
             with stage(backend, "h2d"):
                 batched, _per = self._to_batched(block)
                 dev = self._h2d(batched)
                 dev.block_until_ready()
-            return self._kernel_call(mats, plan, dev)
+            return self._step()(mats, dev)
 
         def drain(up_fut, n: int):
             out = up_fut.result()
@@ -317,7 +226,7 @@ class MeshCodec:
                 block = np.asarray(block, dtype=np.uint8)
                 if block.shape[1] == 0:
                     # empty block still rides the queue so ordering
-                    # holds (same contract as JaxCodec's stream)
+                    # holds (same contract as PallasCodec's stream)
                     f: Future = Future()
                     f.set_result((np.zeros((m, 0), dtype=np.uint8),
                                   _time.perf_counter()))

@@ -56,7 +56,7 @@ def backends_initialized() -> bool:
 
 def setup_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache for an entry point
-    (cli.main, chip_smoke.py, bench.py; never the tests). The directory
+    (cli.main, chip_smoke.py; never the tests). The directory
     is JAX_COMPILATION_CACHE_DIR when set — JAX reads it itself — and
     otherwise the fixed in-tree CACHE_DIR. Every compile is kept: the
     codec kernels compile in well under JAX's default one-second floor

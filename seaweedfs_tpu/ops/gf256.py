@@ -18,7 +18,7 @@ Two representations are maintained:
    which is exactly the shape of work the TPU MXU is built for (integer
    0/1 matmul accumulates exactly in bf16/f32 for k*8 <= 256 terms... and
    exactly in f32 always). This module builds those matrices; the batched
-   device kernels live in codec_jax.py / codec_pallas.py.
+   device kernels live in codec_pallas.py / codec_mesh.py.
 
 Everything here is pure numpy + python ints; no jax imports (host-side).
 """

@@ -24,9 +24,9 @@ This module builds the optimized program once per coefficient matrix:
     never slower than unscheduled at any probed size: both run once at
     first sight of a bucket, the winner is cached.
 
-Everything here is host-side numpy + pure python; the jitted jax
-executor lives in codec_jax (it needs jax), the C executor in
-native/gf256_codec.cc.
+Everything here is host-side numpy + pure python; the executor that
+serves requests is the C one in native/gf256_codec.cc (codec_native).
+The device codecs run the dense bit-plane kernel only.
 """
 from __future__ import annotations
 
@@ -234,12 +234,8 @@ class Chooser:
     callers pass the nbytes of the sample they actually measure so the
     cached verdict is keyed by a probed size. `on`/`off`
     (SEAWEEDFS_TPU_EC_SCHEDULE) pin the answer for tests and benches.
-
-    `background=True` moves the measurement off the caller's thread:
-    the first sight of a (matrix, bucket) kicks a worker thread and
-    serves the dense kernel until the verdict lands — device backends
-    use this because their warm calls include an XLA compile that
-    would otherwise stall the first live read/repair for seconds."""
+    A caller that arrives while another thread measures the same key
+    gets the dense answer instead of measuring twice."""
 
     max_keys: int = 256
     _won: "OrderedDict[tuple[bytes, int], bool]" = field(
@@ -248,8 +244,7 @@ class Chooser:
     _lock: threading.Lock = field(default_factory=threading.Lock)
 
     def use_scheduled(self, coef: np.ndarray, nbytes: int,
-                      run_sched, run_dense,
-                      background: bool = False) -> bool:
+                      run_sched, run_dense) -> bool:
         m = mode()
         if m == "off":
             return False
@@ -269,15 +264,6 @@ class Chooser:
             if key in self._pending:
                 return False  # measurement in flight: dense meanwhile
             self._pending.add(key)
-        if background:
-            # non-daemon ON PURPOSE: a daemon thread killed mid-XLA-
-            # compile at interpreter shutdown aborts the process
-            # (std::terminate); joining at exit costs at most one
-            # compile and only when a measurement is in flight
-            threading.Thread(
-                target=self._measure, args=(key, run_sched, run_dense),
-                name="ec-sched-measure", daemon=False).start()
-            return False
         return self._measure(key, run_sched, run_dense)
 
     def _measure(self, key, run_sched, run_dense) -> bool:

@@ -246,7 +246,7 @@ def test_sync_in_jitted_function_fires(tmp_path):
 
 
 def test_feed_sync_outside_drain_site_fires(tmp_path):
-    run = _run(tmp_path, {"seaweedfs_tpu/ops/codec_jax.py": (
+    run = _run(tmp_path, {"seaweedfs_tpu/ops/codec_pallas.py": (
         "def submit_path(dev):\n"
         "    dev.block_until_ready()\n"
         "def drain(fut):\n"

@@ -159,18 +159,18 @@ class TestBreakerTripAndRecover:
 
 class TestDegradedReadCodecPin:
     def test_interval_reconstruct_pinned_to_cpu_codec(self, tmp_path):
-        """With -ec.backend=jax forced, single-needle degraded reads
+        """With -ec.backend=pallas forced, single-needle degraded reads
         still reconstruct on the native/CPU codec — a device dispatch
         on a GET's critical path is pure latency."""
         from seaweedfs_tpu.ec.backend import cpu_backend_name
         from seaweedfs_tpu.storage.store import Store
 
         store = Store([str(tmp_path)], ip="127.0.0.1", port=0,
-                      ec_backend="jax")
+                      ec_backend="pallas")
         ecv = types.SimpleNamespace(k=10, m=4)
         rs = store._rs_for(ecv, interval=True)
         assert rs.backend.name == cpu_backend_name()
         assert rs.backend.name in ("native", "numpy")
-        assert rs.backend.name != "jax"
+        assert rs.backend.name != "pallas"
         # whole-volume ops keep the configured device backend
-        assert store.ec_backend == "jax"
+        assert store.ec_backend == "pallas"

@@ -5,7 +5,7 @@ import pytest
 from seaweedfs_tpu.ec import backend as ecb
 from seaweedfs_tpu.ops import codec_numpy
 
-BACKENDS = ["numpy", "jax"]
+BACKENDS = ["numpy", "pallas"]
 
 
 @pytest.fixture(scope="module")
@@ -81,20 +81,39 @@ def test_wide_code_rs28_4(rng):
 
 def test_jax_slab_chunking(rng):
     """Columns beyond one slab are processed in chunks with identical bits."""
-    from seaweedfs_tpu.ops.codec_jax import JaxCodec
+    from seaweedfs_tpu.ops.codec_pallas import COL_TILE, PallasCodec
 
-    codec = JaxCodec(slab=256)
+    codec = PallasCodec(slab=COL_TILE)
     coef = rng.integers(0, 256, (4, 10)).astype(np.uint8)
-    data = rng.integers(0, 256, (10, 1000)).astype(np.uint8)
+    data = rng.integers(0, 256, (10, COL_TILE + 1000)).astype(np.uint8)
     want = codec_numpy.coded_matmul(coef, data)
     assert np.array_equal(codec.coded_matmul(coef, data), want)
 
 
 def test_backend_registry():
     assert "numpy" in ecb.backend_names()
-    assert "jax" in ecb.backend_names()
+    assert "pallas" in ecb.backend_names()
+    assert "jax" not in ecb.backend_names()
     with pytest.raises(KeyError):
         ecb.get_backend("nope")
+
+
+@pytest.mark.parametrize("cmd", ["volume", "server"])
+def test_ec_backend_flag_rejects_unknown_name(cmd, capsys):
+    """-ec.backend takes only registered names: a removed or mistyped
+    one fails at start-up and names the known backends, not at a
+    server's first EC job."""
+    from seaweedfs_tpu import cli
+
+    assert cli.parse_args([cmd, "-ec.backend=pallas"]).ec_backend == \
+        "pallas"
+    with pytest.raises(SystemExit) as exc:
+        cli.parse_args([cmd, "-ec.backend=jax"])
+    assert exc.value.code == 2
+    err = capsys.readouterr().err
+    assert "invalid choice: 'jax'" in err
+    for name in ecb.backend_names():
+        assert name in err
 
 
 # ---------------------------------------------------------------------
@@ -106,9 +125,9 @@ def test_pipelined_stream_matches_numpy_all_depths(depth, rng):
     """The depth-N staged pipeline is bit-identical to the numpy
     oracle at every depth — including uneven final blocks, a width
     under one lane tile, and an empty block mid-stream."""
-    from seaweedfs_tpu.ops.codec_jax import JaxCodec
+    from seaweedfs_tpu.ops.codec_pallas import COL_TILE, PallasCodec
 
-    codec = JaxCodec(slab=1024)
+    codec = PallasCodec(slab=COL_TILE)
     coef = rng.integers(0, 256, (4, 10)).astype(np.uint8)
     widths = [1000, 512, 257, 0, 64, 777, 3]
     blocks = [rng.integers(0, 256, (10, w)).astype(np.uint8)
@@ -150,7 +169,7 @@ def _mk_curve(cpu_mbps, rows, device=True):
         "cpu_mbps": cpu_mbps,
         "device": ({"platform": "tpu", "kind": "test", "count": 1}
                    if device else None),
-        "device_backend": "jax",
+        "device_backend": "pallas",
     }
 
 
@@ -202,12 +221,12 @@ def test_router_picks_device_when_measured_faster(monkeypatch):
         (1 << 20, 1): 50.0, (4 << 20, 2): 250.0,
         (16 << 20, 2): 900.0, (64 << 20, 4): 2000.0}))
     assert ecb._decide(fast, 1 << 20) == "numpy"
-    assert ecb._decide(fast, 64 << 20) == "jax"
+    assert ecb._decide(fast, 64 << 20) == "pallas"
     from seaweedfs_tpu.ec import probe
 
     monkeypatch.setattr(probe, "_curves", {"": fast})
     assert ecb.choose_backend_for_size(1 << 20) == "numpy"
-    assert ecb.choose_backend_for_size(64 << 20) == "jax"
+    assert ecb.choose_backend_for_size(64 << 20) == "pallas"
     assert ecb.pipeline_depth_for(64 << 20) == 4
 
 

@@ -1,7 +1,7 @@
 """Pallas fused codec kernel: bit-exactness against the numpy codec
 and the backend plumbing. Runs in pallas interpret mode so it works on
-the CPU test mesh; the real-TPU path is exercised by bench/verify runs
-(kernel: seaweedfs_tpu/ops/codec_pallas.py).
+the CPU test mesh; the real-TPU path is exercised by the benchmark and
+chip_smoke.py (kernel: seaweedfs_tpu/ops/codec_pallas.py).
 """
 import numpy as np
 import pytest

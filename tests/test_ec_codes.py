@@ -401,22 +401,21 @@ def test_degraded_gather_skips_dependent_rows(rng, tmp_path):
     assert any(16 in sids for sids in asked[1:])
 
 
-def test_chooser_background_measures_off_thread(monkeypatch):
-    """Regression (device codecs): `background=True` must return the
-    dense verdict immediately and measure on a worker thread — device
-    warm-up includes an XLA compile that would otherwise stall the
-    first live read for seconds. Concurrent callers during the
-    measurement also get dense, without starting a second one."""
+def test_chooser_measures_once_under_concurrent_callers(monkeypatch):
+    """A caller that arrives while another thread measures the same
+    (matrix, bucket) gets the dense answer at once instead of starting
+    a second measurement; the verdict serves everyone afterwards."""
     import threading
     import time
 
     monkeypatch.setenv("SEAWEEDFS_TPU_EC_SCHEDULE", "auto")
     ch = schedule.Chooser()
     coef = rs_matrix.parity_rows(10, 4)
-    gate = threading.Event()
+    started, gate = threading.Event(), threading.Event()
     sched_runs = []
 
     def run_sched():
+        started.set()
         gate.wait(10)
         sched_runs.append(1)
 
@@ -424,22 +423,22 @@ def test_chooser_background_measures_off_thread(monkeypatch):
         time.sleep(0.002)
 
     n = schedule.MIN_SCHED_BYTES
-    assert ch.use_scheduled(coef, n, run_sched, run_dense,
-                            background=True) is False
-    assert ch.use_scheduled(coef, n, run_sched, run_dense,
-                            background=True) is False  # in flight
+    first = []
+    t = threading.Thread(target=lambda: first.append(
+        ch.use_scheduled(coef, n, run_sched, run_dense)))
+    t.start()
+    assert started.wait(10)
     assert ch.snapshot()["measuring"] == 1
+    assert ch.use_scheduled(coef, n, run_sched, run_dense) is False
     gate.set()
-    deadline = time.monotonic() + 10
-    while ch.snapshot()["measuring"] and time.monotonic() < deadline:
-        time.sleep(0.005)
+    t.join(10)
     snap = ch.snapshot()
     assert snap["measuring"] == 0 and snap["buckets"] == 1
     # warm + timed = exactly one measurement despite two callers
     assert len(sched_runs) == 2
-    # verdict landed: the scheduled closure beat the 2ms dense one
-    assert ch.use_scheduled(coef, n, run_sched, run_dense,
-                            background=True) is True
+    # the scheduled closure beat the 2ms dense one
+    assert first == [True]
+    assert ch.use_scheduled(coef, n, run_sched, run_dense) is True
 
 
 def test_native_sample_cap_keys_verdict_by_probed_size(rng, monkeypatch):

@@ -224,9 +224,8 @@ def test_client_ec_cache_follows_shard_move(cluster):
 def test_single_interval_reconstruct_latency_budget():
     """Degraded-read latency budget (VERDICT r2 item 7): recovering ONE
     1MB interval from k=10 shards through the Store's synchronous codec
-    must stay in single-digit-milliseconds territory on the CPU path —
-    the p50 the bench records (bench.py bench_degraded_read_p50). The
-    budget is deliberately loose (CI VMs share cores) but tight enough
+    must stay in single-digit-milliseconds territory on the CPU path.
+    The budget is deliberately loose (CI VMs share cores) but tight enough
     to catch an accidental O(n^2) or a fallen-off fast path."""
     import time
 

@@ -2,12 +2,12 @@
 router's DEVICE arm executed through the real production file paths,
 golden-bits-checked — not just coded_matmul units.
 
-Under the test conftest (JAX_PLATFORMS=cpu, 8 virtual devices) the
-device backend is "jax", which runs the exact same depth-bounded
-streaming pipeline (H2D/compute/D2H via JaxCodec slabbing) the pallas
-backend shares; on a machine with a real accelerator the same test
-rides it with the fused pallas kernel. Either way, write_ec_files and
-rebuild_ec_files run their device-streaming arm end to end.
+The device backend is "pallas": under the test conftest
+(JAX_PLATFORMS=cpu, 8 virtual devices) its kernel runs interpreted
+through the same depth-bounded streaming pipeline (H2D/compute/D2H,
+slabbing) it runs compiled on a real accelerator. Either way,
+write_ec_files and rebuild_ec_files run their device-streaming arm end
+to end.
 """
 import os
 
@@ -19,12 +19,8 @@ from seaweedfs_tpu.ec.encoder import (rebuild_ec_files, verify_ec_files,
                                       write_ec_files)
 
 
-def _device_backend() -> str:
-    import jax
-
-    if any(d.platform != "cpu" for d in jax.devices()):
-        return "pallas"  # real accelerator: the fused kernel path
-    return "jax"  # CPU test mesh: same streaming pipeline, XLA kernel
+# interpreted on the CPU test mesh
+DEVICE_BACKEND = "pallas"
 
 
 @pytest.fixture()
@@ -48,7 +44,7 @@ def _shard_bytes(base):
 
 def test_device_encode_golden_bits(volume, tmp_path):
     base, data = volume
-    backend = _device_backend()
+    backend = DEVICE_BACKEND
     # small chunk: several streaming pipeline iterations, not one
     write_ec_files(base, backend=backend, chunk=1 << 20,
                    small_block=256 << 10)
@@ -68,7 +64,7 @@ def test_device_encode_golden_bits(volume, tmp_path):
 
 def test_device_rebuild_golden_bits(volume):
     base, _ = volume
-    backend = _device_backend()
+    backend = DEVICE_BACKEND
     write_ec_files(base, backend=backend, chunk=1 << 20,
                    small_block=256 << 10)
     golden = _shard_bytes(base)
@@ -87,7 +83,7 @@ def test_env_override_routes_auto(volume, monkeypatch):
     from seaweedfs_tpu.ec import backend as ecb
 
     base, _ = volume
-    backend = _device_backend()
+    backend = DEVICE_BACKEND
     monkeypatch.setenv("SEAWEEDFS_TPU_EC_BACKEND", backend)
     ecb._auto_choice = None
     try:

@@ -181,14 +181,15 @@ class TestEncodeRebuildDecode:
         assert count == 300
 
     def test_jax_backend_encode_identical(self, fixture_volume, tmp_path):
-        """CPU and TPU(jax) backends must produce byte-identical shards."""
+        """CPU and device (pallas) backends must produce byte-identical
+        shards."""
         base = fixture_volume
         _encode(base, backend="numpy")
         cpu_shards = {i: open(base + geo.shard_ext(i), "rb").read()
                       for i in range(14)}
         for i in range(14):
             os.remove(base + geo.shard_ext(i))
-        _encode(base, backend="jax")
+        _encode(base, backend="pallas")
         for i in range(14):
             assert open(base + geo.shard_ext(i), "rb").read() == \
                 cpu_shards[i], i

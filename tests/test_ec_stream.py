@@ -25,7 +25,7 @@ def _reset_auto_choice():
 
 def test_stream_matches_sync_jax():
     rs_sync = ReedSolomon(10, 4, backend="numpy")
-    rs_dev = ReedSolomon(10, 4, backend="jax")
+    rs_dev = ReedSolomon(10, 4, backend="pallas")
     assert rs_dev.supports_streaming
     rng = np.random.default_rng(7)
     blocks = [rng.integers(0, 256, (10, w), dtype=np.uint8)
@@ -51,7 +51,7 @@ def test_stream_fallback_sync_backend():
 
 def test_stream_recovery_rows():
     # the rebuild path streams with a recovery matrix, not parity rows
-    rs = ReedSolomon(10, 4, backend="jax")
+    rs = ReedSolomon(10, 4, backend="pallas")
     rng = np.random.default_rng(9)
     data = rng.integers(0, 256, (10, 5000), dtype=np.uint8)
     parity = ReedSolomon(10, 4, backend="numpy").encode(data)
@@ -104,7 +104,7 @@ def test_write_ec_files_auto_streaming(tmp_path, monkeypatch):
 
     rng = np.random.default_rng(11)
     payload = rng.integers(0, 256, 3 << 20, dtype=np.uint8).tobytes()
-    for sub, backend in (("a", "numpy"), ("b", "auto"), ("c", "jax")):
+    for sub, backend in (("a", "numpy"), ("b", "auto"), ("c", "pallas")):
         base = tmp_path / sub / "1"
         os.makedirs(base.parent)
         (base.parent / "1.dat").write_bytes(payload)
@@ -116,16 +116,16 @@ def test_write_ec_files_auto_streaming(tmp_path, monkeypatch):
         assert (tmp_path / "b" / ("1" + shard_ext(i))).read_bytes() \
             == golden, f"auto shard {i} diverges"
         assert (tmp_path / "c" / ("1" + shard_ext(i))).read_bytes() \
-            == golden, f"jax streaming shard {i} diverges"
+            == golden, f"pallas streaming shard {i} diverges"
 
-    # streamed rebuild: drop two shards from the jax copy, rebuild, compare
+    # streamed rebuild: drop two shards from the pallas copy, rebuild, compare
     base = str(tmp_path / "c" / "1")
     for i in (0, 12):
         os.unlink(base + shard_ext(i))
-    assert sorted(rebuild_ec_files(base, backend="jax",
+    assert sorted(rebuild_ec_files(base, backend="pallas",
                                    chunk=1 << 18)) == [0, 12]
     for i in (0, 12):
         golden = (tmp_path / "a" / ("1" + shard_ext(i))).read_bytes()
         assert (tmp_path / "c" / ("1" + shard_ext(i))).read_bytes() \
             == golden
-    assert verify_ec_files(base, backend="jax", chunk=1 << 18)
+    assert verify_ec_files(base, backend="pallas", chunk=1 << 18)

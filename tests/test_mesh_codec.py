@@ -215,7 +215,7 @@ def _mk_curve(cpu_mbps, rows=(), mesh_rows=(), device=True):
         "cpu_mbps": cpu_mbps,
         "device": ({"platform": "tpu", "kind": "test", "count": 8}
                    if device else None),
-        "device_backend": "jax",
+        "device_backend": "pallas",
     }
     if mesh_rows:
         curve["mesh_rows"] = list(mesh_rows)
@@ -237,7 +237,7 @@ def test_router_picks_mesh_when_fastest(monkeypatch):
                       mesh_rows=_rows({(1 << 20, 1): 100.0,
                                        (64 << 20, 4): 4000.0}))
     # small requests can't amortize the scatter: single-chip wins
-    assert ecb._decide(curve, 1 << 20) == "jax"
+    assert ecb._decide(curve, 1 << 20) == "pallas"
     # bulk rides the mesh
     assert ecb._decide(curve, 64 << 20) == "mesh"
     monkeypatch.setattr(probe, "_curves", {"": curve})

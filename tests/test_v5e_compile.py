@@ -16,12 +16,11 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 from seaweedfs_tpu.ec import geometry as geo
-from seaweedfs_tpu.ops import codec_jax, codec_pallas, rs_matrix
-from seaweedfs_tpu.ops import schedule
+from seaweedfs_tpu.ops import bits, codec_pallas, rs_matrix
 
-# the production dispatch widths: PallasCodec's slab, JaxCodec's slab
+# PallasCodec's production slab, and a bulk block for the XLA kernel
 PALLAS_COLS = 8 << 20
-XLA_COLS = codec_jax.DEFAULT_SLAB
+XLA_COLS = 2 << 20
 
 
 @pytest.fixture(scope="module")
@@ -74,18 +73,9 @@ def test_pallas_kernel(one_chip, spec):
 
 def test_xla_dense_kernel(one_chip):
     m, k = _parity("10.4").shape
-    compiled = codec_jax._bit_matmul_donated.lower(
+    compiled = jax.jit(bits.coded_matmul_bits).lower(
         _spec((8 * m, 8 * k), jnp.bfloat16, one_chip),
         _spec((k, XLA_COLS), jnp.uint8, one_chip)).compile()
-    assert compiled.memory_analysis() is not None
-
-
-def test_xla_scheduled_xor_kernel(one_chip):
-    coef = _parity("10.4")
-    plan = schedule.plan_for(coef)
-    compiled = codec_jax._xor_matmul.lower(
-        plan, _spec((coef.shape[1], XLA_COLS), jnp.uint8,
-                    one_chip)).compile()
     assert compiled.memory_analysis() is not None
 
 
