@@ -16,9 +16,11 @@ matrices from ops.rs_matrix.
 """
 from __future__ import annotations
 
+import contextlib
 import os
+import threading
 import time as _time
-from typing import Callable, Protocol
+from typing import Callable, Iterator, Protocol
 
 import numpy as np
 
@@ -563,6 +565,39 @@ class AutoCodec:
 _register_builtins()
 
 
+# host staging buffers for reconstruct's input stack, kept across jobs:
+# an allocation over glibc's mmap threshold is a fresh mapping whose
+# pages fault in on first touch, so a stack that reuses one skips them
+STAGING_KEEP = 2
+_staging_lock = threading.Lock()
+_staging_free: list[np.ndarray] = []
+
+
+@contextlib.contextmanager
+def staging_buffer(nbytes: int) -> Iterator[np.ndarray]:
+    """A writable 1-D uint8 buffer of at least `nbytes`, the caller's
+    alone until the block ends: the smallest free one that fits, else
+    a new one. On exit it goes back to the free list, which keeps the
+    STAGING_KEEP largest. Counted in ec_staging_buffers_total{outcome=
+    reused|allocated}."""
+    with _staging_lock:  # the free list is kept smallest first
+        i = next((i for i, b in enumerate(_staging_free)
+                  if b.nbytes >= nbytes), None)
+        buf = None if i is None else _staging_free.pop(i)
+    metrics.counter_add("ec_staging_buffers_total", 1,
+                        {"outcome": "allocated" if buf is None
+                         else "reused"})
+    if buf is None:
+        buf = np.empty(nbytes, dtype=np.uint8)
+    try:
+        yield buf
+    finally:
+        with _staging_lock:
+            _staging_free.append(buf)
+            _staging_free.sort(key=lambda b: b.nbytes)
+            del _staging_free[:-STAGING_KEEP]
+
+
 class ReedSolomon:
     """RS(k, m) erasure codec over a pluggable coded-matmul backend.
 
@@ -624,11 +659,21 @@ class ReedSolomon:
         return out
 
     def reconstruct(self, shards: dict[int, np.ndarray],
-                    missing: list[int] | None = None) -> dict[int, np.ndarray]:
+                    missing: list[int] | None = None, *,
+                    stage: np.ndarray | None = None
+                    ) -> dict[int, np.ndarray]:
         """Recover shards from any >= k present ones.
 
         shards: {shard_id: (n,) or (n_cols,) uint8 row}; missing: which ids
         to produce (default: all absent ids 0..k+m-1). Returns {id: row}.
+
+        stage: an optional writable 1-D uint8 buffer the caller owns
+        (staging_buffer()). When it holds the input rows, they are
+        stacked into it instead of a fresh array; else np.stack as
+        without it. The returned rows never view it, and coded_matmul
+        is synchronous, so the caller may refill it once this returns.
+        Never hand such a buffer to coded_matmul_stream: its upload
+        runs on another thread after the call returns.
         """
         present = sorted(shards)
         if missing is None:
@@ -637,8 +682,13 @@ class ReedSolomon:
             return {}
         rows, inputs = rs_matrix.recovery_rows_for(self.code, present,
                                                    missing)
-        stack = np.stack([np.asarray(shards[i], dtype=np.uint8)
-                          for i in inputs])
+        ins = [np.asarray(shards[i], dtype=np.uint8) for i in inputs]
+        size = len(ins) * ins[0].size
+        if stage is not None and stage.size >= size:
+            stack = np.stack(ins, out=stage[:size].reshape(
+                (len(ins),) + ins[0].shape))
+        else:
+            stack = np.stack(ins)
         t0 = _time.perf_counter()
         out = self.backend.coded_matmul(rows, stack)
         observe_codec("reconstruct", self.backend,

@@ -1722,7 +1722,7 @@ class VolumeServer:
                                  chunk: int) -> dict:
         import numpy as np
 
-        from ..ec.backend import ReedSolomon
+        from ..ec.backend import ReedSolomon, staging_buffer
         from ..ec.encoder import code_of
         from ..rpc.httpclient import session
 
@@ -1909,15 +1909,18 @@ class VolumeServer:
         # two chunks are in hand. Gathers run one at a time on that
         # worker, the only thread touching the plan and the byte
         # counters; leaving the block joins it, so no gather outlives
-        # the rebuild and the counters are final below.
+        # the rebuild and the counters are final below. Every chunk is
+        # stacked, here, into one pooled buffer of k rows (the widest
+        # read set of any path) whose pages earlier jobs faulted in.
         ranges = [(off, min(chunk, shard_size - off))
                   for off in range(0, shard_size, chunk)]
         written = 0
         files = {s: open(base + geo.shard_ext(s), "wb")
                  for s in missing}
         try:
-            with ThreadPoolExecutor(
-                    1, thread_name_prefix="ec-rebuild-gather") as ahead:
+            with staging_buffer(k * ranges[0][1]) as stage, \
+                    ThreadPoolExecutor(
+                        1, thread_name_prefix="ec-rebuild-gather") as ahead:
 
                 def gather_ahead(i: int) -> Future:
                     return ahead.submit(contextvars.copy_context().run,
@@ -1931,7 +1934,8 @@ class VolumeServer:
                     if i + 1 < len(ranges):
                         pending = gather_ahead(i + 1)
                     with tracing.interval("ec.rebuild.reconstruct"):
-                        rec = rs.reconstruct(rows, missing=missing)
+                        rec = rs.reconstruct(rows, missing=missing,
+                                             stage=stage)
                     with tracing.interval("ec.rebuild.write"):
                         for s in missing:
                             row = np.asarray(rec[s],

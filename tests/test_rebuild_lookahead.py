@@ -2,8 +2,9 @@
 the gather of chunk i+1 runs while chunk i is reconstructed and written.
 The rebuilt shards stay byte-exact for every code and loss shape, a
 fault on either side leaves no torn shard and no gather thread behind,
-and the positional shard reads it relies on stay exact under
-concurrency."""
+the positional shard reads it relies on stay exact under concurrency,
+and each chunk's input stack lands in a pooled staging buffer that is
+safe to refill once reconstruct returns."""
 import os
 import secrets
 import sys
@@ -13,13 +14,16 @@ import time
 import numpy as np
 import pytest
 
+from seaweedfs_tpu.ec import backend as ecb
 from seaweedfs_tpu.ec import geometry as geo
 from seaweedfs_tpu.ec.backend import ReedSolomon
 from seaweedfs_tpu.ec.volume import EcVolumeShard
 from seaweedfs_tpu.operation import verbs
+from seaweedfs_tpu.ops import codec_numpy, rs_matrix
 from seaweedfs_tpu.server.cluster import Cluster
 from seaweedfs_tpu.shell import commands_ec
 from seaweedfs_tpu.shell.env import CommandEnv, ShellError
+from seaweedfs_tpu.utils import metrics
 
 CHUNK = 128 << 10
 GATHER_THREAD = "ec-rebuild-gather"
@@ -35,6 +39,11 @@ def _until(fn, what, limit=20.0):
 def _gather_threads():
     return [t.name for t in threading.enumerate()
             if t.name.startswith(GATHER_THREAD)]
+
+
+def _staging(outcome):
+    return metrics._counters.get(
+        ("ec_staging_buffers_total", (("outcome", outcome),)), 0.0)
 
 
 class _Deployment:
@@ -166,6 +175,14 @@ def test_pipelined_rebuild_is_byte_exact(case, four, tmp_path):
         assert out["rebuilt_bytes"] == shard_size * len(lost)
         _assert_rebuilt(dep, vid, code, golden)
         assert not _gather_threads()
+        # the same loss again: the job stacks into the buffer the first
+        # one returned to the pool, and rebuilds the same bytes
+        dep.lose(vid, lost)
+        reused, allocated = _staging("reused"), _staging("allocated")
+        dep.rebuild(rebuilder, vid, lost)
+        assert _staging("reused") == reused + 1
+        assert _staging("allocated") == allocated
+        _assert_rebuilt(dep, vid, code, golden)
     finally:
         if dep is not four:
             dep.close()
@@ -192,12 +209,12 @@ def test_lookahead_gathers_next_chunk_during_reconstruct(four, monkeypatch):
     reconstruct = ReedSolomon.reconstruct
     calls = []
 
-    def waiting_reconstruct(self, shards, missing=None):
+    def waiting_reconstruct(self, shards, missing=None, **kw):
         calls.append(len(calls))
         if calls == [0] and not second_fetch.wait(10):
             raise RuntimeError("chunk 1 was not gathered during chunk "
                                "0's reconstruct")
-        return reconstruct(self, shards, missing=missing)
+        return reconstruct(self, shards, missing=missing, **kw)
 
     monkeypatch.setattr(srv, "_remote_shards_fetch_sync", marked_fetch)
     monkeypatch.setattr(ReedSolomon, "reconstruct", waiting_reconstruct)
@@ -267,11 +284,11 @@ def test_caller_fault_stops_the_gather(four, monkeypatch):
     reconstruct = ReedSolomon.reconstruct
     calls = []
 
-    def failing_reconstruct(self, shards, missing=None):
+    def failing_reconstruct(self, shards, missing=None, **kw):
         calls.append(len(calls))
         if len(calls) == 3:
             raise RuntimeError("codec fault on chunk 2")
-        return reconstruct(self, shards, missing=missing)
+        return reconstruct(self, shards, missing=missing, **kw)
 
     monkeypatch.setattr(srv, "_remote_shards_fetch_sync", recorded_fetch)
     monkeypatch.setattr(ReedSolomon, "reconstruct", failing_reconstruct)
@@ -342,3 +359,161 @@ def test_read_at_loops_on_short_positional_reads(shard_file, monkeypatch):
                         lambda fd, n, off: pread(fd, min(n, 4097), off))
     assert shard.read_at(1000, 50_000) == data[1000:51_000]
     assert shard.read_at(len(data) - 9000, 20_000) == data[-9000:]
+
+
+# ---------------------------------------------------------------------
+# staging buffers for the per-chunk input stack
+# ---------------------------------------------------------------------
+
+STAGE_W = 4096
+
+
+def _stripe(code, width, seed):
+    data = np.random.default_rng(seed).integers(
+        0, 256, (code.k, width), dtype=np.uint8)
+    parity = codec_numpy.coded_matmul(rs_matrix.parity_rows_for(code),
+                                      data)
+    return np.concatenate([data, parity])
+
+
+class _Recording:
+    """The numpy codec, recording each stack it is handed."""
+    name = "recording"
+
+    def __init__(self):
+        self.stacks = []
+
+    def coded_matmul(self, coef, shards):
+        self.stacks.append(shards)
+        return codec_numpy.coded_matmul(coef, shards)
+
+
+# the partial rebuild's loss on each code: one data shard, or a server
+LOSSES = {"10.4": [1], "lrc-12.2.2": [1], "28.4": [0, 8, 16, 24]}
+# buffer rows against the code's k: exact, more, one short of the inputs
+STAGE_CASES = {"full_chunk": (STAGE_W, 0), "short_last_chunk": (1000, 0),
+               "more_rows_than_inputs": (STAGE_W, 3),
+               "too_small": (STAGE_W, None)}
+
+
+@pytest.mark.parametrize("case", list(STAGE_CASES))
+@pytest.mark.parametrize("spec", list(LOSSES))
+def test_staged_reconstruct_matches_np_stack(spec, case):
+    code = geo.parse_code(spec)
+    width, extra = STAGE_CASES[case]
+    stripe = _stripe(code, width, 17)
+    lost = LOSSES[spec]
+    shards = {s: stripe[s] for s in range(code.total) if s not in lost}
+    rec = _Recording()
+    rs = ReedSolomon(0, 0, backend=rec, code=code)
+    _, inputs = rs_matrix.recovery_rows_for(code, sorted(shards), lost)
+    rows = len(inputs) - 1 if extra is None else code.k + extra
+    stage = np.empty(rows * STAGE_W, dtype=np.uint8)
+    want = rs.reconstruct(shards, missing=lost)
+    got = rs.reconstruct(shards, missing=lost, stage=stage)
+    assert sorted(got) == lost
+    for s in lost:
+        assert got[s].tobytes() == want[s].tobytes() == stripe[s].tobytes()
+    plain, staged = rec.stacks
+    assert np.array_equal(plain, staged)
+    assert np.shares_memory(staged, stage) == (extra is not None)
+
+
+@pytest.mark.parametrize("backend", ["numpy", "pallas"])
+def test_rows_survive_refilling_the_stage(backend):
+    code = geo.parse_code("10.4")
+    stripe = _stripe(code, STAGE_W, 3)
+    shards = {s: stripe[s] for s in range(code.total) if s != 2}
+    rs = ReedSolomon(0, 0, backend=backend, code=code)
+    stage = np.empty(code.k * STAGE_W, dtype=np.uint8)
+    got = rs.reconstruct(shards, missing=[2], stage=stage)
+    before = got[2].copy()
+    assert not np.shares_memory(got[2], stage)
+    stage.fill(0xA5)
+    assert np.array_equal(got[2], before)
+    assert np.array_equal(got[2], stripe[2])
+
+
+def test_staged_chunks_go_through_the_codec_and_are_counted():
+    """One coded_matmul per chunk, on the backend (so a fault wrapper
+    stays in the path), and ec_codec_bytes_total{op=reconstruct} counts
+    every stacked byte."""
+    code = geo.parse_code("10.4")
+    stripe = _stripe(code, 2 * STAGE_W + 777, 9)
+    rec = _Recording()
+    rs = ReedSolomon(0, 0, backend=rec, code=code)
+    key = ("ec_codec_bytes_total",
+           (("backend", "recording"), ("op", "reconstruct")))
+    before = metrics._counters.get(key, 0.0)
+    stage = np.empty(code.k * STAGE_W, dtype=np.uint8)
+    stacked = 0
+    for off in range(0, stripe.shape[1], STAGE_W):
+        chunk = stripe[:, off:off + STAGE_W]
+        shards = {s: chunk[s] for s in range(code.total) if s != 4}
+        got = rs.reconstruct(shards, missing=[4], stage=stage)
+        assert np.array_equal(got[4], chunk[4])
+        stacked += code.k * chunk.shape[1]
+    assert len(rec.stacks) == 3
+    assert all(np.shares_memory(st, stage) for st in rec.stacks)
+    assert metrics._counters.get(key, 0.0) - before == stacked
+
+
+def test_staging_pool_is_exclusive_bounded_and_reused(monkeypatch):
+    monkeypatch.setattr(ecb, "_staging_free", [])
+    held, both = [], threading.Barrier(2, timeout=10)
+
+    def hold():
+        with ecb.staging_buffer(1 << 16) as buf:
+            held.append(buf)
+            both.wait()
+
+    threads = [threading.Thread(target=hold) for _ in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(10)
+    assert not any(t.is_alive() for t in threads)
+    assert len(held) == 2 and not np.shares_memory(*held)
+    assert held[0].nbytes >= 1 << 16 and held[1].nbytes >= 1 << 16
+
+    # many holders at once: a buffer handed to two of them would see
+    # the other's mark, and the free list stays bounded throughout
+    clashes = []
+
+    def churn(mark):
+        for i in range(300):
+            with ecb.staging_buffer(64 + i % 3) as buf:
+                buf[:64] = mark
+                time.sleep(0)
+                if not (buf[:64] == mark).all():
+                    clashes.append(mark)
+            if len(ecb._staging_free) > ecb.STAGING_KEEP:
+                clashes.append("kept")
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=churn, args=(i,))
+                   for i in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert not clashes
+    ecb._staging_free[:] = held
+    with ecb.staging_buffer(10), ecb.staging_buffer(20), \
+            ecb.staging_buffer(1 << 17):
+        pass
+    assert len(ecb._staging_free) == ecb.STAGING_KEEP == 2
+    reused, allocated = _staging("reused"), _staging("allocated")
+    with ecb.staging_buffer(1 << 16) as buf:
+        assert any(buf is b for b in held)
+    assert (_staging("reused"), _staging("allocated")) == \
+        (reused + 1, allocated)
+    with ecb.staging_buffer(1 << 18) as big:
+        assert big.nbytes == 1 << 18
+    assert _staging("allocated") == allocated + 1
+    assert [b.nbytes for b in ecb._staging_free] == [1 << 17, 1 << 18]
