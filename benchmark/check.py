@@ -1,5 +1,7 @@
 """What decides `correct`: the timed path's answers against the plain
-reference (reference.py), byte for byte, after the window.
+reference, byte for byte, after the window. The reference is
+reference.py, or, where a configuration brings its own,
+benchmark/references/<config name>.py (found by spec.reference).
 
 Every number compared has the limit 0, because GF(256) is exact and a
 read returns the stored bytes or it does not:
@@ -20,6 +22,13 @@ read returns the stored bytes or it does not:
 - device_short_bytes: bytes the device codec was due to move and did
   not, counting as due at least each encoded .dat and each rebuilt
   shard.
+
+Under `ec_backend: auto` the router may rightly choose the CPU codec,
+so there is no device guarantee to hold: offdevice_codec_bytes is not
+a check, and device_short_bytes counts the due bytes that no concrete
+backend moved (a job the codec skipped, or bytes left under the
+unresolved label `auto`). The window's coded bytes by backend and op
+go in the run's line as information.
 """
 from __future__ import annotations
 
@@ -28,7 +37,7 @@ import os
 
 import numpy as np
 
-from . import reference
+from . import reference, spec
 from .deploy import total
 
 
@@ -43,18 +52,36 @@ def shard_diff(path: str, want: np.ndarray) -> int:
 
 
 def reference_shards(dat_path: str, config: dict,
-                     which: list[int] | None = None) -> dict:
+                     which: list[int] | None = None,
+                     root: str = spec.ROOT) -> dict:
+    """Reference bytes of the shards in `which` of the .dat at
+    `dat_path`: the configuration's own reference where it brings one,
+    else reference.py's."""
+    shards = spec.reference(config.get("name", ""), root) or \
+        reference.shards
     dat = np.fromfile(dat_path, dtype=np.uint8)
-    return reference.shards(dat, config["code"],
-                            config["large_block_bytes"],
-                            config["small_block_bytes"], which)
+    return shards(dat, config["code"], config["large_block_bytes"],
+                  config["small_block_bytes"], which)
+
+
+def codec_bytes_by_backend(counters: dict) -> dict:
+    """{backend: {op: bytes}} of the window's coding, under the labels
+    the codec recorded: information, compared with no limit."""
+    out: dict = {}
+    for (name, labels), v in counters.items():
+        if name == "ec_codec_bytes_total" and v:
+            lab = dict(labels)
+            by_op = out.setdefault(lab["backend"], {})
+            by_op[lab["op"]] = by_op.get(lab["op"], 0.0) + v
+    return out
 
 
 def codec_checks(traffic, counters: dict, config: dict) -> dict:
     """Which codec moved the jobs' bytes, from the window's counters.
     Single-needle reads reconstruct on the CPU codec by design, so in
-    a mix with reads the off-device count is not taken for rebuilds."""
-    dev = config["ec_backend"]
+    a mix with reads the off-device count is not taken for rebuilds.
+    Under `auto` the bytes any concrete backend moved count as coded."""
+    auto = config["ec_backend"] == "auto"
     ops = {"encode": "encode", "rebuild": "reconstruct"}
     out = {}
     job_ops = {j["op"] for j in traffic.jobs if not j.get("warm")}
@@ -62,18 +89,23 @@ def codec_checks(traffic, counters: dict, config: dict) -> dict:
     for op in job_ops:
         name = ops[op]
         moved = total(counters, "ec_codec_bytes_total", op=name)
-        on_dev = total(counters, "ec_codec_bytes_total", op=name,
-                       backend=dev)
-        if not (name == "reconstruct" and traffic.reads):
-            off += moved - on_dev
+        if auto:
+            coded = moved - total(counters, "ec_codec_bytes_total",
+                                  op=name, backend="auto")
+        else:
+            coded = total(counters, "ec_codec_bytes_total", op=name,
+                          backend=config["ec_backend"])
+            if not (name == "reconstruct" and traffic.reads):
+                off += moved - coded
         due = 0
         for j in traffic.jobs:
             if j.get("warm") or j.get("op") != op or "end" not in j:
                 continue
             due += j["rebuilt_bytes"] if op == "rebuild" \
                 else j["dat_bytes"]
-        short += max(0.0, due - on_dev)
-    out["offdevice_codec_bytes"] = off
+        short += max(0.0, due - coded)
+    if not auto:
+        out["offdevice_codec_bytes"] = off
     out["device_short_bytes"] = short
     return out
 
@@ -96,7 +128,8 @@ def read_checks(traffic) -> dict:
     return {"reads_wrong": wrong, "reads_failed": failed}
 
 
-def shard_checks(traffic, config: dict) -> tuple[dict, list]:
+def shard_checks(traffic, config: dict,
+                 root: str = spec.ROOT) -> tuple[dict, list]:
     """Shards the window produced against the reference; returns the
     numbers and the (path, shard id) pairs compared."""
     pairs: list[tuple[str, int]] = []
@@ -110,7 +143,7 @@ def shard_checks(traffic, config: dict) -> tuple[dict, list]:
     if which:
         from concurrent.futures import ThreadPoolExecutor
 
-        ref = reference_shards(traffic.ref_dat, config, which)
+        ref = reference_shards(traffic.ref_dat, config, which, root)
         with ThreadPoolExecutor(8) as ex:
             wrong = sum(ex.map(lambda p: shard_diff(p[0], ref[p[1]]),
                                pairs))

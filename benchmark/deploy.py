@@ -3,12 +3,23 @@ one master and N volume servers (server/cluster.py Cluster), each in a
 rack of its own, every one with the configuration's codec backend, and
 the shell's commands driven against it. Also the counters the cell
 reads from the servers' /metrics, and JAX's compile events.
+
+The codec router's probe curve (ec/probe.py, `-ec.backend=auto`) is
+kept in the run's own work directory: an `auto` run sweeps in its own
+set-up, and no run, whatever its backend, reads a curve another left.
 """
 from __future__ import annotations
 
 import os
 import re
 import time
+
+
+# the single-chip device codec: the one `-ec.backend=auto` hands out on
+# a one-chip host where its measured feed beats the CPU codec
+# (ec/backend.py DEVICE_BACKENDS, ec/probe.py run_sweep)
+AUTO_DEVICE_BACKEND = "pallas"
+PROBE_CACHE_ENV = "SEAWEEDFS_TPU_EC_PROBE_CACHE"
 
 
 class BenchError(Exception):
@@ -74,6 +85,14 @@ def total(counters: dict, name: str, **labels) -> float:
                if n == name and want <= set(lab))
 
 
+def device_backend(config: dict) -> str:
+    """The codec label under which the configuration's device coding is
+    counted: its `ec_backend`, or for `auto` the single-chip device
+    codec, the name the router records when it picks the device."""
+    name = config["ec_backend"]
+    return AUTO_DEVICE_BACKEND if name == "auto" else name
+
+
 def delta(after: dict, before: dict) -> dict:
     return {k: v - before.get(k, 0.0) for k, v in after.items()}
 
@@ -90,6 +109,8 @@ class Deployment:
         self.dirs = [os.path.join(work, f"vol{i}_0") for i in range(self.n)]
         for d in self.dirs:
             os.makedirs(d, exist_ok=True)
+        self.probe_cache = os.path.join(work, "probe", "ec_probe.json")
+        os.environ[PROBE_CACHE_ENV] = self.probe_cache
         self.cluster = None
         self.env = None
 

@@ -7,6 +7,14 @@ runs plant none.
 - unchanged: the codec hands back its output buffer unwritten;
 - half: the first half of every block's columns left out;
 - offdevice: the device codec's work done by the native CPU codec.
+
+Under `ec_backend: auto` a fault is planted in every concrete codec the
+router can hand out on this host, the single-chip device codec and the
+CPU codec, so it reaches whichever side the router picks; `offdevice`
+has no device guarantee to break there and is refused. Where the file
+encoder takes the native codec, it codes the whole file in one native
+call (native.ec_encode_file) and not through the codec object: the
+fault alters that call's parity files as it returns.
 """
 from __future__ import annotations
 
@@ -48,15 +56,39 @@ class _Faulty:
             yield _alter(self._fault, out)
 
 
-def install(fault: str, device_backend: str) -> None:
+def _faulty_file_encode(inner, fault: str):
+    """native.ec_encode_file, its parity files passed through `_alter`."""
+    def encode(dat_path, shard_paths, coef, k, m, *args, **kw):
+        inner(dat_path, shard_paths, coef, k, m, *args, **kw)
+        for path in shard_paths[k:]:
+            out = np.fromfile(path, dtype=np.uint8)
+            if out.size:
+                _alter(fault, out).tofile(path)
+    return encode
+
+
+def install(fault: str, config: dict) -> None:
     """Plant `fault` in this process's codec registry, before any
     server builds a codec."""
     from seaweedfs_tpu.ec import backend as ecb
 
+    from .deploy import device_backend
+
     if fault not in FAULTS:
         raise ValueError(f"unknown fault {fault!r}; known: {FAULTS}")
+    device = device_backend(config)
     if fault == "offdevice":
-        ecb._instances[device_backend] = ecb.get_backend("native")
+        if config["ec_backend"] == "auto":
+            raise ValueError("fault 'offdevice' under ec_backend auto: "
+                             "the router may rightly choose the CPU "
+                             "codec, so there is no device guarantee "
+                             "to break")
+        ecb._instances[device] = ecb.get_backend("native")
         return
-    for name in {device_backend, ecb.cpu_backend_name()}:
+    for name in {device, ecb.cpu_backend_name()}:
         ecb._instances[name] = _Faulty(ecb.get_backend(name), fault)
+    if config["ec_backend"] in ("auto", "native"):
+        from seaweedfs_tpu import native
+
+        native.ec_encode_file = _faulty_file_encode(native.ec_encode_file,
+                                                    fault)

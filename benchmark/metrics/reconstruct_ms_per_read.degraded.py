@@ -2,14 +2,14 @@
 ec_codec_seconds{op=reconstruct} of the CPU codec (single-needle
 degraded reads are pinned to it, storage/store.py _rs_for), over the
 reads. A program span, host clock."""
-from benchmark.deploy import total
+from benchmark.deploy import device_backend, total
 
 
 def read(run):
     reads = run["reads"]
     if not reads:
         return None
-    dev = run["config"]["ec_backend"]
+    dev = device_backend(run["config"])
     c = run["counters"]
     spent = total(c, "ec_codec_seconds_sum", op="reconstruct") - \
         total(c, "ec_codec_seconds_sum", op="reconstruct", backend=dev)

@@ -11,7 +11,7 @@ count (2 * 8r * 8k per column, bf16) is printed as information only.
 """
 from __future__ import annotations
 
-from .deploy import total
+from .deploy import device_backend, total
 from .trace_reduce import peaks
 
 OPS = {"encode": "encode", "rebuild": "reconstruct"}
@@ -19,7 +19,7 @@ OPS = {"encode": "encode", "rebuild": "reconstruct"}
 
 def coded_bytes(run, op: str) -> tuple[float, float]:
     """(input, output) bytes of the window's device coding of `op`."""
-    dev = run["config"]["ec_backend"]
+    dev = device_backend(run["config"])
     moved = total(run["counters"], "ec_codec_bytes_total", op=OPS[op],
                   backend=dev)
     if op == "encode":

@@ -13,10 +13,11 @@ window is traced by the JAX profiler and the per-layer metrics are
 printed instead of the end-to-end ones.
 
 The last line of standard output is one JSON object: correct,
-attempted, failed, metrics, device, (with --trace 1) breakdown, and
-last `checks`, each number compared beside its limit; the same checks
-end standard error. Off the chip it exits non-zero and prints no
-result, unless JAX_PLATFORMS=cpu asks for a CPU rehearsal.
+attempted, failed, metrics, device, (with --trace 1) breakdown, (under
+`ec_backend: auto`) codec_bytes_by_backend, and last `checks`, each
+number compared beside its limit; the same checks end standard error.
+Off the chip it exits non-zero and prints no result, unless
+JAX_PLATFORMS=cpu asks for a CPU rehearsal.
 """
 from __future__ import annotations
 
@@ -85,6 +86,20 @@ def start_trace(path: str) -> None:
     jax.profiler.start_trace(path, profiler_options=opts)
 
 
+def probe_sweeps(dep) -> dict:
+    """{curve file: sweep seconds} of the codec router's probe curves
+    this run's set-up measured and saved (only a curve with a device
+    measured is saved)."""
+    probe_dir = os.path.dirname(dep.probe_cache)
+    if not os.path.isdir(probe_dir):
+        return {}
+    out = {}
+    for name in sorted(os.listdir(probe_dir)):
+        with open(os.path.join(probe_dir, name), encoding="utf-8") as f:
+            out[name] = json.load(f).get("sweep_seconds")
+    return out
+
+
 def run_cell(args, spec_: dict, work: str) -> dict:
     import jax
 
@@ -110,7 +125,7 @@ def run_cell(args, spec_: dict, work: str) -> dict:
     if args.fault:
         from benchmark import faults
 
-        faults.install(args.fault, config["ec_backend"])
+        faults.install(args.fault, config)
     traffic = spec.traffic_class(spec_)(spec_["traffic"], config, args.seed,
                                         work)
     dep = Deployment(os.path.join(work, "cluster"), config)
@@ -122,6 +137,8 @@ def run_cell(args, spec_: dict, work: str) -> dict:
     try:
         traffic.setup(dep)
         phase("sealing, losses and warm-up")
+        if config["ec_backend"] == "auto":
+            say("probe sweeps " + json.dumps(probe_sweeps(dep)))
         say("setup compiles " + json.dumps(compiles.snapshot()))
         trace_dir = os.path.join(work, "trace")
         if args.trace:
@@ -164,7 +181,7 @@ def run_cell(args, spec_: dict, work: str) -> dict:
               "shards_unmounted": unmounted}
     checks.update(check.codec_checks(traffic, counters, config))
     t_ref = time.monotonic()
-    shard_numbers, pairs = check.shard_checks(traffic, config)
+    shard_numbers, pairs = check.shard_checks(traffic, config, args.root)
     checks.update(shard_numbers)
     if traffic.spec.get("reads"):
         checks.update(check.read_checks(traffic))
@@ -236,6 +253,13 @@ def report(args, spec_: dict, run: dict) -> dict:
         out["breakdown"] = {"device_ops": t["device_ops"],
                             "idle_gaps": t["idle_gaps"]}
     out["compiles_in_window"] = run["compiles_in_window"]["xla_compiles"]
+    if run["config"]["ec_backend"] == "auto":
+        from benchmark import check
+
+        out["codec_bytes_by_backend"] = check.codec_bytes_by_backend(
+            run["counters"])
+        say("codec bytes by backend " +
+            json.dumps(out["codec_bytes_by_backend"]))
     out["checks"] = {k: {"value": v, "limit": 0}
                      for k, v in run["checks"].items()}
     return out

@@ -10,7 +10,14 @@ a traffic mix or a metric by adding files and entries only:
   benchmark.traffic.Traffic) and its parameters as `PARAMS`;
 - each metric: a reader <root>/benchmark/metrics/<metric name>.py with
   `def read(run) -> float | None`, returning None where it finds
-  nothing to read.
+  nothing to read;
+- a configuration's own plain reference, where reference.py does not
+  describe its code (a code whose shard bytes are not a per-column map
+  of the data bytes, such as a sub-striped one):
+  <root>/benchmark/references/<config name>.py with `def shards(dat,
+  code, large, small, which) -> dict[int, np.ndarray]`, of the same
+  meaning as reference.shards. A configuration without one is checked
+  against reference.py.
 """
 from __future__ import annotations
 
@@ -95,3 +102,12 @@ def reader(metric: str, root: str = ROOT):
     if not os.path.exists(path):
         raise SpecError(f"metric {metric!r} has no reader at {path}")
     return _load_module(path, "benchmark_metric_").read
+
+
+def reference(config: str, root: str = ROOT):
+    """The `shards` function of <root>/benchmark/references/<config>.py,
+    or None where the configuration brings no reference of its own."""
+    path = os.path.join(root, "benchmark", "references", config + ".py")
+    if not config or not os.path.exists(path):
+        return None
+    return _load_module(path, "benchmark_reference_").shards

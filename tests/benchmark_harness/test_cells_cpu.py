@@ -17,8 +17,12 @@ from . import tiny
 
 CELLS = ["rs10_4.seal", "rs10_4.rebuild1", "lrc12_2_2.rebuild1"]
 FAULTS = ["flip", "unchanged", "half", "offdevice"]
+# the seal mix under `ec_backend: auto`, the router's control
+AUTO_FAULTS = [(tiny.AUTO_CELL, "flip")]
 RUNS = [(c, "", 1) for c in CELLS] + [(tiny.READS_CELL, "", 0)] + \
-    [(c, f, 0) for c in CELLS for f in FAULTS]
+    [(tiny.AUTO_CELL, "", 0)] + \
+    [(c, f, 0) for c in CELLS for f in FAULTS] + \
+    [(c, f, 0) for c, f in AUTO_FAULTS]
 KEYS = {"correct", "attempted", "failed", "metrics", "device", "checks"}
 
 
@@ -44,7 +48,7 @@ def results(tmp_path_factory):
         return {r: f.result() for r, f in futs.items()}
 
 
-@pytest.mark.parametrize("cell", CELLS + [tiny.READS_CELL])
+@pytest.mark.parametrize("cell", CELLS + [tiny.READS_CELL, tiny.AUTO_CELL])
 def test_sound_run_is_correct(results, cell):
     trace = int(cell in CELLS)
     res = results[(cell, "", trace)]
@@ -69,7 +73,8 @@ def test_sound_run_is_correct(results, cell):
 
 
 @pytest.mark.parametrize("cell,fault",
-                         [(c, f) for c in CELLS for f in FAULTS])
+                         [(c, f) for c in CELLS for f in FAULTS] +
+                         AUTO_FAULTS)
 def test_fault_is_not_correct(results, cell, fault):
     res = results[(cell, fault, 0)]
     assert res["rc"] == 0, res["err"]
@@ -80,3 +85,15 @@ def test_fault_is_not_correct(results, cell, fault):
         assert "offdevice_codec_bytes" in failing
     else:
         assert "shard_bytes_wrong" in failing
+
+
+def test_auto_run_names_the_router_choice(results):
+    """Off the chip the router has no device to measure and picks the
+    CPU codec: every coded byte under that concrete name, none under
+    `auto`, and no device guarantee checked."""
+    line = results[(tiny.AUTO_CELL, "", 0)]["line"]
+    by_backend = line["codec_bytes_by_backend"]
+    assert set(by_backend) <= {"native", "numpy"}, by_backend
+    assert sum(b.get("encode", 0) for b in by_backend.values()) > 0
+    assert "offdevice_codec_bytes" not in line["checks"]
+    assert list(line)[-1] == "checks"

@@ -69,3 +69,49 @@ def test_lost_shard_left_out_of_the_rebuild_is_wrong(tmp_path, ref):
     gen.jobs = [{"op": "rebuild", "snap": snap, "rebuilt": [3]}]
     got, _ = check.shard_checks(gen, CONFIG)
     assert got["shard_bytes_wrong"] == shards[6].size
+
+
+def _counters(by_backend: dict) -> dict:
+    return {("ec_codec_bytes_total", (("backend", b), ("op", "encode"))): v
+            for b, v in by_backend.items()}
+
+
+# two encode jobs of 1,000 and 500 bytes in the window, the warm-up job
+# outside it; the codec's counters as each case leaves them
+SEAL = types.SimpleNamespace(reads=[], jobs=[
+    {"op": "encode", "warm": True, "dat_bytes": 700, "end": 0.5},
+    {"op": "encode", "dat_bytes": 1000, "end": 1.0},
+    {"op": "encode", "dat_bytes": 500, "end": 2.0}])
+
+
+@pytest.mark.parametrize("backend,coded,want", [
+    # a concrete backend: the checks as they always were
+    ("pallas", {"pallas": 1500}, {"offdevice_codec_bytes": 0,
+                                  "device_short_bytes": 0}),
+    ("pallas", {"pallas": 1000, "native": 500},
+     {"offdevice_codec_bytes": 500, "device_short_bytes": 500}),
+    # auto: any backend the router named moves the bytes
+    ("auto", {"pallas": 1500}, {"device_short_bytes": 0}),
+    ("auto", {"native": 1500}, {"device_short_bytes": 0}),
+    ("auto", {"pallas": 900, "native": 600}, {"device_short_bytes": 0}),
+    # bytes left under the unresolved label, and a job the codec skipped
+    ("auto", {"auto": 1500}, {"device_short_bytes": 1500}),
+    ("auto", {"pallas": 1000}, {"device_short_bytes": 500}),
+], ids=["pallas", "pallas-offdevice", "auto-pallas", "auto-native",
+        "auto-both", "auto-unresolved", "auto-skipped-job"])
+def test_codec_checks(backend, coded, want):
+    counters = _counters(coded)
+    got = check.codec_checks(SEAL, counters, {"ec_backend": backend})
+    assert got == want
+    assert check.codec_bytes_by_backend(counters) == {
+        b: {"encode": v} for b, v in coded.items()}
+
+
+def test_offdevice_fault_under_auto_is_refused():
+    from benchmark import faults
+    from seaweedfs_tpu.ec import backend as ecb
+
+    before = dict(ecb._instances)
+    with pytest.raises(ValueError, match="no device guarantee"):
+        faults.install("offdevice", {"ec_backend": "auto"})
+    assert ecb._instances == before

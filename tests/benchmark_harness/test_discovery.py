@@ -106,3 +106,70 @@ def test_unknown_cell_and_missing_reader_are_errors(tmp_path):
         spec.load_cell("no.such_cell")
     with pytest.raises(spec.SpecError):
         spec.reader("no_such_metric", str(tmp_path))
+
+
+_CODE = {"code": {"spec": "10.4", "k": 10, "local": 0, "global": 4},
+         "large_block_bytes": 1 << 20, "small_block_bytes": 1 << 10}
+# a configuration's own reference: reference.py's bytes, one flipped
+_FLIP_ONE = ("from benchmark import reference\n"
+             "def shards(dat, code, large, small, which=None):\n"
+             "    out = reference.shards(dat, code, large, small, which)\n"
+             "    out[min(out)] = out[min(out)].copy()\n"
+             "    out[min(out)][0] ^= 1\n"
+             "    return out\n")
+
+
+def _dat(tmp_path) -> str:
+    import numpy as np
+
+    path = str(tmp_path / "ref.dat")
+    np.random.default_rng(5).integers(0, 256, 30_000, dtype=np.uint8) \
+        .tofile(path)
+    return path
+
+
+def test_configuration_reference_is_found_before_the_plain_one(tmp_path):
+    import numpy as np
+
+    from benchmark import check, reference
+
+    root = str(tmp_path)
+    _write(os.path.join(root, "benchmark", "references", "own_code.py"),
+           _FLIP_ONE)
+    dat = _dat(tmp_path)
+    plain = reference.shards(np.fromfile(dat, dtype=np.uint8),
+                             _CODE["code"], _CODE["large_block_bytes"],
+                             _CODE["small_block_bytes"])
+    assert spec.reference("own_code", root) is not None
+    own = check.reference_shards(dat, dict(_CODE, name="own_code"),
+                                 root=root)
+    assert sorted(own) == sorted(plain)
+    assert np.count_nonzero(own[0] != plain[0]) == 1
+    # a configuration without a file of its own falls back to reference.py
+    assert spec.reference("other_code", root) is None
+    other = check.reference_shards(dat, dict(_CODE, name="other_code"),
+                                   root=root)
+    assert all(np.array_equal(other[s], plain[s]) for s in plain)
+
+
+def test_configuration_reference_decides_shard_bytes_wrong(tmp_path):
+    """Shards that match reference.py read wrong where the
+    configuration's own reference says otherwise."""
+    import types
+
+    from benchmark import check
+
+    root = str(tmp_path / "root")
+    _write(os.path.join(root, "benchmark", "references", "own_code.py"),
+           _FLIP_ONE)
+    dat = _dat(tmp_path)
+    plain = check.reference_shards(dat, _CODE)
+    paths = {}
+    for sid, want in plain.items():
+        paths[sid] = str(tmp_path / f"7.ec{sid:02d}")
+        want.tofile(paths[sid])
+    t = types.SimpleNamespace(ref_dat=dat, jobs=[], sealed_paths={7: paths})
+    got, _ = check.shard_checks(t, dict(_CODE, name="plain_code"), root)
+    assert got["shard_bytes_wrong"] == 0
+    got, _ = check.shard_checks(t, dict(_CODE, name="own_code"), root)
+    assert got["shard_bytes_wrong"] == 1

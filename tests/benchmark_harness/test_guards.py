@@ -1,6 +1,7 @@
 """The harness refuses to run where it would measure the wrong thing:
 off the chip without JAX_PLATFORMS=cpu, on fewer chips than the cell
-asks for, and without the program beside it."""
+asks for, and without the program beside it; and no run reads the
+codec router's probe curve that another run left."""
 from __future__ import annotations
 
 import os
@@ -43,3 +44,21 @@ def test_benchmark_alone_prints_no_result(tmp_path):
     assert p.returncode != 0
     assert p.stdout.strip() == ""
     assert "seaweedfs_tpu" in p.stderr
+
+
+def test_each_run_keeps_its_own_probe_cache(tmp_path, monkeypatch):
+    from seaweedfs_tpu.ec import probe
+
+    from benchmark import deploy
+
+    monkeypatch.setenv(deploy.PROBE_CACHE_ENV, str(tmp_path / "shared"))
+    seen = []
+    for name in ("run_a", "run_b"):
+        work = str(tmp_path / name / "cluster")
+        dep = deploy.Deployment(work, {"volume_servers": 1})
+        assert os.environ[deploy.PROBE_CACHE_ENV] == dep.probe_cache
+        # the default curve and a per-code one both lie in the run's dir
+        for code in ("", "12.2.2"):
+            assert probe.cache_path(code).startswith(work + os.sep)
+        seen.append(os.path.dirname(dep.probe_cache))
+    assert seen[0] != seen[1]
