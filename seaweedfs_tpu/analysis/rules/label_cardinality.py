@@ -33,12 +33,13 @@ _FUNCS = {"counter_add", "gauge_set", "histogram_observe"}
 # {queue, fsync, replicate, ack} — bounded by the pipeline shape,
 # never per-request data. `name` is a phase or step name of
 # utils/tracing (span_seconds): string literals at the span/interval
-# call sites, a fixed set by construction.
+# call sites, a fixed set by construction. `substripe` is a repair
+# input's part of a shard: exactly {whole, a, b}.
 ALLOWED = {
     "backend", "code", "collection", "dir", "direction", "from",
     "handler", "instance", "kind", "le", "method", "mode", "name", "op",
-    "outcome", "q", "reason", "service", "shard", "stage", "tenant",
-    "tier", "to",
+    "outcome", "q", "reason", "service", "shard", "stage", "substripe",
+    "tenant", "tier", "to",
 }
 
 # `le` is emitted by the histogram renderer itself and `direction` by

@@ -162,8 +162,9 @@ _CODE_ENV = "SEAWEEDFS_TPU_EC_CODE"
 # the LRC configs (local XOR groups cut single-loss repair fan-in from
 # k to the group size at a small storage premium, arXiv 1309.0186).
 # Any well-formed spec works with -ec.code; these are the documented,
-# probed and benched ones.
-KNOWN_CODES = ("10.4", "lrc-10.2.2", "lrc-12.3.2", "28.4")
+# probed and benched ones, with Hitchhiker-XOR on RS(10,4) (a
+# single data-shard repair reads 13-14 half shards, not 10 whole).
+KNOWN_CODES = ("10.4", "lrc-10.2.2", "lrc-12.3.2", "28.4", "hh-10.4")
 
 
 def default_code_spec() -> str:
@@ -647,9 +648,11 @@ class ReedSolomon:
         return cls(0, 0, backend, code=geo.parse_code(codec or ""))
 
     def encode(self, data: np.ndarray) -> np.ndarray:
-        """(k, n) data shards -> (m, n) parity shards."""
+        """(k, n) data shards -> (m, n) parity shards. A sub-striped
+        code takes (2k, n), the data shards' a-halves then b-halves,
+        and gives (2m, n), the parities' in the same order."""
         data = np.asarray(data, dtype=np.uint8)
-        assert data.shape[0] == self.k, data.shape
+        assert data.shape[0] == self._parity_rows.shape[1], data.shape
         t0 = _time.perf_counter()
         out = self.backend.coded_matmul(self._parity_rows, data)
         # label after the call: AutoCodec resolves during its first op
@@ -666,6 +669,8 @@ class ReedSolomon:
 
         shards: {shard_id: (n,) or (n_cols,) uint8 row}; missing: which ids
         to produce (default: all absent ids 0..k+m-1). Returns {id: row}.
+        For a sub-striped code the ids are row ids (code.halfrow): a
+        half of a shard each, all at the same offset within its half.
 
         stage: an optional writable 1-D uint8 buffer the caller owns
         (staging_buffer()). When it holds the input rows, they are
@@ -677,22 +682,33 @@ class ReedSolomon:
         """
         present = sorted(shards)
         if missing is None:
-            missing = [i for i in range(self.n) if i not in shards]
+            missing = [i for i in range(self.code.rows)
+                       if i not in shards]
         if not missing:
             return {}
         rows, inputs = rs_matrix.recovery_rows_for(self.code, present,
                                                    missing)
         ins = [np.asarray(shards[i], dtype=np.uint8) for i in inputs]
-        size = len(ins) * ins[0].size
+        # a backend that compiles a kernel per input-row count may ask
+        # for the count rounded up (row_multiple): zero rows under zero
+        # coefficients, so fan-ins one apart (Hitchhiker's 13 and 14
+        # half ranges) share one kernel and neither compiles mid-loop
+        pad = -len(ins) % getattr(self.backend, "row_multiple", 1)
+        shape = (len(ins) + pad,) + ins[0].shape
+        size = shape[0] * ins[0].size
         if stage is not None and stage.size >= size:
-            stack = np.stack(ins, out=stage[:size].reshape(
-                (len(ins),) + ins[0].shape))
+            stack = stage[:size].reshape(shape)
         else:
-            stack = np.stack(ins)
+            stack = np.empty(shape, dtype=np.uint8)
+        np.stack(ins, out=stack[:len(ins)])
+        if pad:
+            stack[len(ins):] = 0
+            rows = np.concatenate(
+                [rows, np.zeros((len(rows), pad), dtype=np.uint8)], axis=1)
         t0 = _time.perf_counter()
         out = self.backend.coded_matmul(rows, stack)
         observe_codec("reconstruct", self.backend,
-                      _time.perf_counter() - t0, stack.nbytes,
+                      _time.perf_counter() - t0, len(ins) * ins[0].nbytes,
                       code=self.code.spec)
         return {sid: out[i] for i, sid in enumerate(missing)}
 
@@ -742,8 +758,21 @@ class ReedSolomon:
                                       depth=depth, op="encode")
 
     def verify(self, shards: np.ndarray) -> bool:
-        """(k+m, n) full shard stack -> parity consistency check."""
+        """(k+m, n) full shard stack -> parity consistency check. A
+        sub-striped code's shards are coded in halves, so `n` must be
+        whole shards (or matching prefixes of both halves, side by
+        side)."""
         shards = np.asarray(shards, dtype=np.uint8)
         assert shards.shape[0] == self.n
+        subs = self.code.substripes
+        if subs > 1:
+            if shards.shape[1] % subs:
+                return False
+            # one row per half, in code.halfrow order
+            halves = np.concatenate(np.split(shards, subs, axis=1))
+            data = [r for r in range(len(halves)) if r % self.n < self.k]
+            par = [r for r in range(len(halves)) if r % self.n >= self.k]
+            return bool(np.array_equal(self.encode(halves[data]),
+                                       halves[par]))
         expect = self.encode(shards[: self.k])
         return bool(np.array_equal(expect, shards[self.k:]))

@@ -209,6 +209,12 @@ def _write_ec_files(base: str, backend: str, large_block: int,
             rs.backend._resolve()
         backend_name = getattr(rs.backend, "chosen", "") or ""
     rec["peer"] = backend_name
+    if code.substripes > 1:
+        # the coupled encode; never the native whole-file call, which
+        # codes each shard offset on its own
+        _encode_substriped(rs, base, dat_size, large_block, small_block,
+                           chunk)
+        return
     if backend_name == "native" and dat_size:
         # the whole read -> parity -> write loop in one native call:
         # no GIL on either the producer or writer side (the measured
@@ -350,6 +356,89 @@ def _encode_region(rs: ReedSolomon, dat: np.ndarray, start: int, n_rows: int,
         w.close()
 
 
+def _shard_columns(dat: np.ndarray, y0: int, y1: int, n_large: int,
+                   large_block: int, small_block: int,
+                   out: np.ndarray) -> None:
+    """Fill `out` (k, y1-y0) with the data shards' bytes at shard
+    offsets [y0, y1), taken from their .dat offsets through the row
+    layout; zeros past EOF."""
+    k = out.shape[0]
+    total = dat.shape[0]
+    large_end = n_large * large_block
+    y = y0
+    while y < y1:
+        if y < large_end:
+            block = large_block
+            row, inner = divmod(y, block)
+            row_start = row * k * block
+            stop = min(y1, (row + 1) * block)
+        else:
+            block = small_block
+            row, inner = divmod(y - large_end, block)
+            row_start = large_end * k + row * k * block
+            stop = min(y1, large_end + (row + 1) * block)
+        o, w = y - y0, stop - y
+        for j in range(k):
+            src = row_start + j * block + inner
+            got = max(0, min(w, total - src))
+            out[j, o:o + got] = dat[src:src + got]
+            out[j, o + got:o + w] = 0
+        y = stop
+
+
+def _encode_substriped(rs: ReedSolomon, base: str, dat_size: int,
+                       large_block: int, small_block: int,
+                       chunk: int) -> None:
+    """Hitchhiker's coupled encode (hop-and-couple): for each column x
+    of the first half of the shards, the 2k inputs are every data
+    shard at x and at h+x, and one (2m, 2k) coded matmul gives the m
+    parities at x and at h+x, piggybacks included. Each shard file
+    has two sequential writers, one from 0 and one from h."""
+    k, m = rs.k, rs.m
+    n_large, _ = geo.row_layout(dat_size, large_block, small_block,
+                                data_shards=k)
+    half = geo.shard_file_size(dat_size, large_block, small_block,
+                               data_shards=k) // 2
+    dat = np.memmap(base + ".dat", dtype=np.uint8, mode="r") \
+        if dat_size else np.zeros(0, dtype=np.uint8)
+    paths = [base + geo.shard_ext(i) for i in range(k + m)]
+    heads = [open(p, "wb", buffering=0) for p in paths]
+    tails = []
+    from .backend import pipeline_depth_for
+
+    try:
+        for p in paths:
+            tails.append(open(p, "r+b", buffering=0))
+            tails[-1].seek(half)
+        depth = pipeline_depth_for(2 * k * chunk, code=rs.code.spec)
+        w = _AsyncWriter()
+        try:
+            def gen():
+                for x0 in range(0, half, chunk):
+                    x1 = min(x0 + chunk, half)
+                    with tracing.interval("ec.encode.gather"):
+                        cols = np.empty((2 * k, x1 - x0), dtype=np.uint8)
+                        _shard_columns(dat, x0, x1, n_large, large_block,
+                                       small_block, cols[:k])
+                        _shard_columns(dat, half + x0, half + x1, n_large,
+                                       large_block, small_block, cols[k:])
+                    for i in range(k):
+                        w.put(heads[i], cols[i])
+                        w.put(tails[i], cols[k + i])
+                    yield cols
+
+            for parity in rs.encode_stream(gen(), depth=depth):
+                for j in range(m):
+                    w.put(heads[k + j], parity[j])
+                    w.put(tails[k + j], parity[m + j])
+        finally:
+            w.close()
+    finally:
+        for f in heads + tails:
+            f.close()
+        del dat
+
+
 def _gather_columns(dat: np.ndarray, row_start: int, block: int,
                     c0: int, c1: int,
                     k: int = geo.DATA_SHARDS) -> np.ndarray:
@@ -391,6 +480,9 @@ def rebuild_ec_files(base: str, backend: str = "auto",
     if len(sizes) != 1:
         raise ValueError(f"present shards disagree on size: {sizes}")
     shard_size = sizes.pop()
+    if code.substripes > 1:
+        _rebuild_substriped(base, rs, chunk, shard_size, present, missing)
+        return missing
 
     # one recovery matrix serves every chunk; the code's repair plan
     # picks the inputs (an LRC single-loss reads its group, not k), and
@@ -428,9 +520,59 @@ def rebuild_ec_files(base: str, backend: str = "auto",
     return missing
 
 
+def _rebuild_substriped(base: str, rs: ReedSolomon, chunk: int,
+                        shard_size: int, present: list[int],
+                        missing: list[int]) -> None:
+    """rebuild_ec_files for a sub-striped code: the repair plan's
+    (shard, half) reads, column x of each half at a time, and the
+    lost shards' halves written at x and at h+x."""
+    from ..ops import rs_matrix
+    from .backend import pipeline_depth_for
+
+    code = rs.code
+    half = shard_size // 2
+    plan = code.repair_plan(missing, present)
+    targets = [code.halfrow(s, h) for s in missing for h in range(2)]
+    rows, inputs = rs_matrix.recovery_rows_for(
+        code, [code.halfrow(s, h) for s, h in plan.reads], targets)
+    reads = [code.split_row(r) for r in inputs]
+    ins = {s: np.memmap(base + geo.shard_ext(s), dtype=np.uint8,
+                        mode="r") for s, _ in reads} if half else {}
+    heads = {s: open(base + geo.shard_ext(s), "wb", buffering=0)
+             for s in missing}
+    tails = {}
+    try:
+        for s in missing:
+            tails[s] = open(base + geo.shard_ext(s), "r+b", buffering=0)
+            tails[s].seek(half)
+
+        def gen():
+            for x0 in range(0, half, chunk):
+                x1 = min(x0 + chunk, half)
+                yield np.stack([np.asarray(ins[s][h * half + x0:
+                                                  h * half + x1])
+                                for s, h in reads])
+
+        depth = pipeline_depth_for(len(inputs) * chunk, code=code.spec)
+        w = _AsyncWriter()
+        try:
+            for rec in rs.matmul_stream(rows, gen(), depth=depth,
+                                        op="reconstruct"):
+                for j, r in enumerate(targets):
+                    s, h = code.split_row(r)
+                    w.put((tails if h else heads)[s], rec[j])
+        finally:
+            w.close()
+    finally:
+        for f in list(heads.values()) + list(tails.values()):
+            f.close()
+
+
 def verify_ec_files(base: str, backend: str = "auto",
                     chunk: int = DEFAULT_CHUNK) -> bool:
-    """Parity-check the full shard set (scrub building block)."""
+    """Parity-check the full shard set (scrub building block). A
+    sub-striped code checks its halves side by side: column x of
+    every shard at x and at h+x."""
     code = code_of(base)
     k, m = code.k, code.m
     rs = ReedSolomon(k, m, backend=backend, code=code)
@@ -439,19 +581,27 @@ def verify_ec_files(base: str, backend: str = "auto",
         return False
     size = os.path.getsize(paths[0])
     maps = [np.memmap(p, dtype=np.uint8, mode="r") for p in paths]
-    for m in maps:
-        if m.shape[0] != size:
+    for mm in maps:
+        if mm.shape[0] != size:
             return False
+    subs = code.substripes
+    if size % subs:
+        return False
+    part = size // subs
+    # one stack row per code row (code.halfrow order)
+    data = [r for r in range(code.rows) if r % code.total < k]
+    par = [r for r in range(code.rows) if r % code.total >= k]
     from collections import deque
 
     expected: deque = deque()
 
     def gen():
-        for c0 in range(0, size, chunk):
-            c1 = min(c0 + chunk, size)
-            stack = np.stack([np.asarray(m[c0:c1]) for m in maps])
-            expected.append(stack[k:])
-            yield stack[:k]
+        for c0 in range(0, part, chunk):
+            c1 = min(c0 + chunk, part)
+            stack = np.stack([np.asarray(mm[h * part + c0:h * part + c1])
+                              for h in range(subs) for mm in maps])
+            expected.append(stack[par])
+            yield stack[data]
 
     for parity in rs.encode_stream(gen()):
         if not np.array_equal(parity, expected.popleft()):

@@ -11,6 +11,11 @@ function takes an optional `data_shards`, and `parse_codec("28.4")`
 names an RS(28,4) volume tier for cold collections (BASELINE config #4:
 wider stripes cost the same MXU dispatch but 1/7th the parity
 overhead). The reference hard-codes 10+4.
+
+Hitchhiker-XOR (`hh-k.m`, Rashmi et al., SIGCOMM 2014) keeps RS(k,m)'s
+data shards and stripe layout, so needle location does not change,
+but codes each shard as two halves (hop-and-couple: byte x is paired
+with byte h+x, h = shard size / 2): see CodeConfig.
 """
 from __future__ import annotations
 
@@ -30,13 +35,14 @@ def parse_codec(codec: str) -> tuple[int, int]:
     """Codec spec -> (data_shards, total_parity_shards).
 
     Accepts 'k.m' (RS), 'lrc-k.l.g' (LRC: l local XOR parities + g
-    global RS parities, total parity l+g), or '' for the RS(10,4)
-    default. Geometry (stripe layout, shard count, locate math) only
-    needs (k, m); code structure lives in parse_code/CodeConfig.
+    global RS parities, total parity l+g), 'hh-k.m' (Hitchhiker-XOR on
+    RS(k,m)), or '' for the RS(10,4) default. Geometry (stripe layout,
+    shard count, locate math) only needs (k, m); code structure lives
+    in parse_code/CodeConfig.
     """
     if not codec:
         return DATA_SHARDS, PARITY_SHARDS
-    if codec.startswith("lrc-"):
+    if codec.startswith(("lrc-", "hh-")):
         code = parse_code(codec)
         return code.k, code.m
     k_s, _, m_s = codec.partition(".")
@@ -64,8 +70,10 @@ class RepairPlan:
     size their IO from it instead of assuming k-of-n."""
 
     missing: tuple[int, ...]
-    reads: tuple[int, ...]
-    kind: str  # "local" (XOR group peel) or "global" (matrix solve)
+    # shard ids; for a sub-striped code (shard, half) ranges, half 0
+    # at shard offset x and half 1 at h+x
+    reads: tuple
+    kind: str  # "local" (XOR group peel), "piggyback" or "global"
 
     @property
     def fanin(self) -> int:
@@ -82,13 +90,24 @@ class CodeConfig:
     [k+l, k+l+g) are global RS parities. A single loss inside a group
     repairs from the k/l surviving group members instead of k shards.
 
+    kind "hh" (hh-k.m, Hitchhiker-XOR with hop-and-couple): two
+    RS(k,m) codewords per shard, a = the bytes at offset x and b = the
+    bytes at h+x (x < h, h = shard size / 2). Shards [0,k) are the
+    data bytes; parity k+i holds f_i(a) at x, and at h+x f_i(b), for
+    i >= 1 XORed with the a-bytes of data group G_i
+    (piggyback_groups). Repairing data shard d of G_i reads 13-14
+    half ranges at RS(10,4) instead of 10 whole shards: the b-halves
+    of the other data shards and parity k (decoding b), parity k+i's
+    b-half, and the a-halves of d's group peers. A shard's two halves
+    are the code's rows halfrow(sid, 0) and halfrow(sid, 1).
+
     The encode/recovery matrices live in ops.rs_matrix
     (encode_matrix_for / recovery_rows_for); this class is pure
     structure so geometry stays importable without numpy-heavy deps.
     """
 
     spec: str
-    kind: str                      # "rs" | "lrc"
+    kind: str                      # "rs" | "lrc" | "hh"
     k: int                         # data shards
     n_local: int                   # local (XOR) parity shards
     n_global: int                  # global (RS) parity shards
@@ -105,6 +124,41 @@ class CodeConfig:
     @property
     def is_rs(self) -> bool:
         return self.kind == "rs"
+
+    @property
+    def substripes(self) -> int:
+        """Ranges a shard is coded in: 2 for Hitchhiker's halves."""
+        return 2 if self.kind == "hh" else 1
+
+    @property
+    def rows(self) -> int:
+        """Coded rows: a shard each, or a half each when sub-striped."""
+        return self.total * self.substripes
+
+    def halfrow(self, sid: int, half: int) -> int:
+        """Row id of shard `sid`'s half `half` (0: offset x, 1: h+x)."""
+        return half * self.total + sid
+
+    def split_row(self, row: int) -> tuple[int, int]:
+        """halfrow's inverse: (shard id, half)."""
+        return row % self.total, row // self.total
+
+    @property
+    def piggyback_groups(self) -> tuple[tuple[int, ...], ...]:
+        """Hitchhiker-XOR: group i's a-bytes ride on parity k+1+i's
+        b-half. k data shards in m-1 consecutive groups, the larger
+        ones last ({0-2}, {3-5}, {6-9} at RS(10,4)); empty for other
+        kinds."""
+        if self.kind != "hh":
+            return ()
+        n = self.m - 1
+        base, extra = divmod(self.k, n)
+        out, start = [], 0
+        for i in range(n):
+            size = base + (i >= n - extra)
+            out.append(tuple(range(start, start + size)))
+            start += size
+        return tuple(out)
 
     @property
     def group_size(self) -> int:
@@ -151,6 +205,7 @@ class CodeConfig:
             "total": self.total,
             "storage_overhead": round(self.storage_overhead, 3),
             "repair_fanin": self.repair_fanin,
+            "substripes": self.substripes,
         }
 
     # -- repair planning ------------------------------------------------
@@ -169,7 +224,9 @@ class CodeConfig:
             return False
         from ..ops import rs_matrix
 
-        return rs_matrix.rank_of(self, present) >= self.k
+        # a sub-striped code needs rank k per substripe, over its halves
+        return rs_matrix.rank_of(self, present) >= \
+            self.k * self.substripes
 
     def repair_plan(self, missing, available) -> RepairPlan | None:
         """The cheapest read set healing `missing` from `available`,
@@ -184,6 +241,8 @@ class CodeConfig:
         avail = set(int(s) for s in available) - set(missing)
         if not missing:
             return RepairPlan((), (), "local")
+        if self.substripes > 1:
+            return self._substripe_plan(missing, avail)
         reads: set[int] = set()
         healed: set[int] = set()
         have = set(avail)
@@ -216,13 +275,42 @@ class CodeConfig:
         reads.update(inputs)
         return RepairPlan(missing, tuple(sorted(reads)), "global")
 
+    def _substripe_plan(self, missing: tuple[int, ...],
+                        avail: set[int]) -> RepairPlan | None:
+        """Hitchhiker: one lost data shard of group G_i reads the
+        b-halves of the other data shards and parity k, parity k+i's
+        b-half and the a-halves of its group peers. Any other loss, or
+        a planned read that is not available, takes a rank solve over
+        every available half (rs_matrix.solve_row_inputs) for exactly
+        the lost shards' halves."""
+        if len(missing) == 1 and missing[0] < self.k:
+            d = missing[0]
+            i, grp = next((i, g) for i, g in
+                          enumerate(self.piggyback_groups) if d in g)
+            reads = [(s, 1) for s in range(self.k + 1) if s != d] + \
+                [(self.k + 1 + i, 1)] + [(s, 0) for s in grp if s != d]
+            if all(s in avail for s, _ in reads):
+                return RepairPlan(missing, tuple(sorted(reads)),
+                                  "piggyback")
+        from ..ops import rs_matrix
+
+        inputs = rs_matrix.solve_row_inputs(
+            self, [self.halfrow(s, h) for h in range(2)
+                   for s in sorted(avail)],
+            [self.halfrow(s, h) for s in missing for h in range(2)])
+        if inputs is None:
+            return None
+        return RepairPlan(missing, tuple(sorted(
+            self.split_row(r) for r in inputs)), "global")
+
 
 @lru_cache(maxsize=64)
 def parse_code(spec: str) -> CodeConfig:
     """Codec spec -> CodeConfig. '' -> RS(10,4); 'k.m' -> RS(k,m);
     'lrc-k.l.g' -> LRC with l local XOR groups and g global parities
-    (k divisible by l). The same strings are recorded in volume .vif
-    files, so mixed-code clusters decode correctly."""
+    (k divisible by l); 'hh-k.m' -> Hitchhiker-XOR on RS(k,m). The
+    same strings are recorded in volume .vif files, so mixed-code
+    clusters decode correctly."""
     if not spec:
         # canonical spec: '' and '10.4' are the same code, one identity
         return CodeConfig(codec_name(DATA_SHARDS, PARITY_SHARDS),
@@ -242,6 +330,16 @@ def parse_code(spec: str) -> CodeConfig:
             raise ValueError(
                 f"code {spec!r}: k+locals+globals > {MAX_SHARD_COUNT}")
         return CodeConfig(spec, "lrc", k, l, g)
+    if spec.startswith("hh-"):
+        parts = spec[len("hh-"):].split(".")
+        if len(parts) != 2:
+            raise ValueError(f"code {spec!r}: expected hh-<k>.<m>")
+        k, m = (int(p) for p in parts)
+        if m < 2 or k < m - 1 or k + m > MAX_SHARD_COUNT:
+            raise ValueError(
+                f"code {spec!r}: need m>=2, k>=m-1, k+m<="
+                f"{MAX_SHARD_COUNT}")
+        return CodeConfig(spec, "hh", k, 0, m)
     k, m = parse_codec(spec)
     return CodeConfig(spec, "rs", k, 0, m)
 

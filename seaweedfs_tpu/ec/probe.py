@@ -285,8 +285,10 @@ def run_sweep(sizes=SWEEP_SIZES, depths=SWEEP_DEPTHS,
     from . import geometry as geo
 
     cfg = geo.parse_code(code or "")
-    k, m = cfg.k, cfg.m
-    coef = rs_matrix.encode_matrix_for(cfg)[k:]
+    # the encode's shape: (m, k) parity rows; a sub-striped code's
+    # coupled encode takes (2m, 2k)
+    coef = rs_matrix.parity_rows_for(cfg)
+    m, k = coef.shape
     if budget_s is None:
         try:
             budget_s = float(os.environ.get(_BUDGET_ENV,

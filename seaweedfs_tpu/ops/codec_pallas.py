@@ -148,6 +148,9 @@ class PallasCodec:
     in slabs of `slab` columns per kernel call."""
 
     name = "pallas"
+    # ReedSolomon.reconstruct rounds the input rows up to a multiple of
+    # this with zero rows: one compiled kernel per pair of fan-ins
+    row_multiple = 2
 
     # bound the coefficient-matrix cache: reconstruction over wide
     # codes can see tens of thousands of distinct recovery matrices

@@ -151,10 +151,36 @@ def _encode_matrix_for_cached(spec: str, k: int, n_local: int,
     return enc.tobytes()
 
 
+@lru_cache(maxsize=16)
+def _substripe_generator_cached(k: int, m: int,
+                                groups: tuple[tuple[int, ...], ...]
+                                ) -> bytes:
+    """Hitchhiker-XOR's (2n, 2k) generator, n = k+m: row h*n + s is
+    shard s's half h, column j the a-byte a_j and column k+j the
+    b-byte b_j of data shard j. Both halves are RS(k,m) codewords;
+    parity k+1+i's b-half also XORs the a-bytes of group i."""
+    n = k + m
+    rs = np.frombuffer(_encode_matrix_cached(k, m), dtype=np.uint8
+                       ).reshape(n, k)
+    g = np.zeros((2 * n, 2 * k), dtype=np.uint8)
+    g[:n, :k] = rs
+    g[n:, k:] = rs
+    for i, grp in enumerate(groups):
+        g[n + k + 1 + i, list(grp)] = 1
+    return g.tobytes()
+
+
 def encode_matrix_for(code) -> np.ndarray:
-    """(total, k) systematic encode matrix of a geometry.CodeConfig:
-    identity on top; for LRC, local XOR indicator rows then global
-    Vandermonde rows; for RS, the classic parity block."""
+    """(rows, symbols) systematic encode matrix of a
+    geometry.CodeConfig: identity on top; for LRC, local XOR indicator
+    rows then global Vandermonde rows; for RS, the classic parity
+    block. For a sub-striped code a row is a shard's half
+    (code.halfrow) and there are substripes * k data symbols."""
+    if code.substripes > 1:
+        raw = _substripe_generator_cached(code.k, code.m,
+                                          code.piggyback_groups)
+        return np.frombuffer(raw, dtype=np.uint8).reshape(
+            code.rows, code.substripes * code.k).copy()
     raw = _encode_matrix_for_cached(code.spec, code.k, code.n_local,
                                     code.n_global)
     return np.frombuffer(raw, dtype=np.uint8).reshape(
@@ -162,8 +188,14 @@ def encode_matrix_for(code) -> np.ndarray:
 
 
 def parity_rows_for(code) -> np.ndarray:
-    """The (m, k) parity coefficient block of a code's encode matrix."""
-    return encode_matrix_for(code)[code.k:, :]
+    """The (m, k) parity coefficient block of a code's encode matrix.
+    Sub-striped: (2m, 2k), the parities' a-halves then b-halves, over
+    the data shards' a-halves then b-halves (one coupled encode)."""
+    enc = encode_matrix_for(code)
+    if code.substripes > 1:
+        return enc[[code.halfrow(s, h) for h in range(code.substripes)
+                    for s in range(code.k, code.total)]]
+    return enc[code.k:, :]
 
 
 def _gf_eliminate(rows: np.ndarray) -> tuple[np.ndarray, list[int]]:
@@ -196,13 +228,26 @@ def _gf_eliminate(rows: np.ndarray) -> tuple[np.ndarray, list[int]]:
 def rank_of(code, present: list[int]) -> int:
     """GF(256) rank of the encode-matrix rows of `present` shards —
     the honest recoverability check (LRC local-parity rows are
-    linearly dependent with their group, so counting survivors lies)."""
-    enc = encode_matrix_for(code)
-    rows = enc[[s for s in sorted(set(present)) if 0 <= s < code.total]]
-    if not len(rows):
+    linearly dependent with their group, so counting survivors lies).
+    A sub-striped code counts every half of each shard."""
+    shards = [s for s in sorted(set(present)) if 0 <= s < code.total]
+    return rank_of_rows(code, [code.halfrow(s, h) for h in
+                               range(code.substripes) for s in shards])
+
+
+def rank_of_rows(code, rows: list[int]) -> int:
+    """GF(256) rank of the encode-matrix rows `rows` (row ids)."""
+    if not rows:
         return 0
-    _, pivots = _gf_eliminate(rows)
-    return len(pivots)
+    return len(_gf_eliminate(encode_matrix_for(code)[list(rows)])[1])
+
+
+def in_span(code, rows: list[int], targets: list[int]) -> bool:
+    """Whether every row of `targets` is a combination of `rows`."""
+    if not rows:
+        return False
+    enc = encode_matrix_for(code)
+    return gf_solve(enc[list(rows)].T, enc[list(targets)].T) is not None
 
 
 def gf_solve(a: np.ndarray, b: np.ndarray) -> np.ndarray | None:
@@ -232,22 +277,61 @@ def solve_inputs(code, available: list[int], missing: list[int],
     encode rows span every missing shard's row. None = unrecoverable.
     Rows that do not grow the span are never added — a dependent local
     parity costs a read without buying information."""
-    enc = encode_matrix_for(code)
     avail = [s for s in sorted(set(available))
              if 0 <= s < code.total and s not in set(missing)]
     prefer = [s for s in (prefer or []) if s in set(avail)]
     ordered = prefer + [s for s in avail if s not in set(prefer)]
-    targets = enc[sorted(set(missing))]
+    return solve_row_inputs(code, ordered, sorted(set(missing)))
+
+
+def solve_row_inputs(code, ordered: list[int], targets: list[int]
+                     ) -> list[int] | None:
+    """The shortest independent prefix (in `ordered`'s order) of the
+    encode-matrix rows `ordered` whose span holds every row of
+    `targets`; None when all of them do not. Solves for the targets
+    alone, not for every data symbol."""
+    enc = encode_matrix_for(code)
+    goal = enc[list(targets)]
     chosen: list[int] = []
-    for sid in ordered:
-        trial = chosen + [sid]
-        basis, pivots = _gf_eliminate(enc[trial])
+    for row in ordered:
+        trial = chosen + [row]
+        _basis, pivots = _gf_eliminate(enc[trial])
         if len(pivots) == len(chosen):  # dependent row: skip
             continue
         chosen = trial
-        if gf_solve(enc[chosen].T, targets.T) is not None:
+        if gf_solve(enc[chosen].T, goal.T) is not None:
             return chosen
     return None
+
+
+def _row_recovery(code, present: list[int], missing: list[int]
+                  ) -> tuple[np.ndarray, list[int]]:
+    """recovery_rows_for over a sub-striped code's halves: the rows
+    read are `present` row ids (the shortest spanning prefix of them),
+    the outputs exactly the `missing` row ids. Cached per read set, as
+    every chunk of a rebuild asks the same."""
+    key = ("rows", code.spec, tuple(present), tuple(missing))
+    with _inv_lock:
+        hit = _inv_cache.get(key)
+        if hit is not None:
+            _inv_cache.move_to_end(key)
+    if hit is not None:
+        raw, inputs = hit
+        return (np.frombuffer(raw, dtype=np.uint8).reshape(
+            len(missing), len(inputs)).copy(), list(inputs))
+    inputs = solve_row_inputs(code, present, missing)
+    if inputs is None:
+        raise ValueError(
+            f"code {code.spec}: rows {missing} unrecoverable from "
+            f"{present}")
+    enc = encode_matrix_for(code)
+    rows = np.ascontiguousarray(
+        gf_solve(enc[inputs].T, enc[missing].T).T)
+    with _inv_lock:
+        _inv_cache[key] = (rows.tobytes(), tuple(inputs))
+        while len(_inv_cache) > INVERSION_CACHE_MAX:
+            _inv_cache.popitem(last=False)
+    return rows, inputs
 
 
 def recovery_rows_for(code, present: list[int], missing: list[int]
@@ -257,9 +341,13 @@ def recovery_rows_for(code, present: list[int], missing: list[int]
         missing = matrix @ stack(shards[i] for i in input_shard_ids)
     For RS this is the classic k-input inversion (cached); for LRC the
     input set follows the code's repair plan — a single group loss
-    reads group_size shards, not k."""
+    reads group_size shards, not k. For a sub-striped code `present`
+    and `missing` are row ids (code.halfrow), not shard ids."""
     if code.is_rs:
         return recovery_rows(code.k, code.m, present, missing)
+    if code.substripes > 1:
+        return _row_recovery(code, sorted(set(int(r) for r in present)),
+                             [int(r) for r in missing])
     missing = sorted(set(int(s) for s in missing))
     plan = code.repair_plan(missing, present)
     if plan is None:

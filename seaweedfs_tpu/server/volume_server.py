@@ -1794,6 +1794,13 @@ class VolumeServer:
                     break
         if not shard_size:
             raise ValueError(f"vid {vid}: cannot stat shard size")
+        # a sub-striped code is coded in ranges of `part` bytes (its
+        # halves), each chunk one column range of all of them
+        subs = code.substripes
+        if shard_size % subs:
+            raise ValueError(f"vid {vid}: {code.spec} shard size "
+                             f"{shard_size} is not {subs} halves")
+        part = shard_size // subs
         rs = ReedSolomon(k, m, backend=self.store.ec_backend,
                          code=code)
         # planned reads (structured codes): which shards each chunk
@@ -1813,7 +1820,7 @@ class VolumeServer:
             plan_remote = [s for s in plan.reads
                            if s not in local_sids]
 
-        if plan is not None:
+        if plan is not None and subs == 1:
             split_plan()
         fetch_deadline = max(30.0, self.store.ec_read_deadline)
 
@@ -1836,6 +1843,49 @@ class VolumeServer:
                     deadline=fetch_deadline, bps=max_bps)
             net_bytes += len(fetched) * n
             return fetched
+
+        def fetch_ranges(ranges: list, n: int) -> dict:
+            nonlocal net_bytes
+            with tracing.interval("ec.rebuild.fetch"):
+                self._repair_throttle_sync(max_bps, len(ranges) * n)
+                fetched = self._remote_ranges_fetch_sync(
+                    vid, ranges, n, need=len(ranges),
+                    deadline=fetch_deadline, bps=max_bps)
+            net_bytes += len(fetched) * n
+            return fetched
+
+        def gather_parts(off: int, n: int) -> dict:
+            """Rows of one chunk of a sub-striped code, keyed by row
+            id: the plan's (shard, half) ranges, half h at h*part+off,
+            the remote ones in one fan-out; re-planned around shards
+            that do not answer."""
+            nonlocal plan
+            while plan is not None:
+                rows: dict[int, object] = {}
+                remote = []
+                for s, h in plan.reads:
+                    if s in local_sids:
+                        rows[code.halfrow(s, h)] = read_local(
+                            s, h * part + off, n)
+                    else:
+                        remote.append((s, h))
+                if not remote:
+                    return rows
+                fetched = fetch_ranges(
+                    [(s, h * part + off) for s, h in remote], n)
+                short = [s for s, h in remote
+                         if (s, h * part + off) not in fetched]
+                if not short:
+                    for s, h in remote:
+                        rows[code.halfrow(s, h)] = np.frombuffer(
+                            fetched[(s, h * part + off)], dtype=np.uint8)
+                    return rows
+                dead.update(short)
+                plan = code.repair_plan(
+                    missing, [s for s in avail if s not in dead])
+            raise ValueError(
+                f"vid {vid}: shards {sorted(dead)} unreachable, "
+                f"{code.spec} shards {missing} have no repair plan")
 
         def gather_planned(off: int, n: int):
             """Rows for one chunk via the repair plan, re-planning
@@ -1900,6 +1950,8 @@ class VolumeServer:
             return rows
 
         def gather(off: int, n: int) -> dict:
+            if subs > 1:
+                return gather_parts(off, n)
             rows = gather_planned(off, n) if plan is not None else None
             return gather_generic(off, n) if rows is None else rows
 
@@ -1910,15 +1962,20 @@ class VolumeServer:
         # worker, the only thread touching the plan and the byte
         # counters; leaving the block joins it, so no gather outlives
         # the rebuild and the counters are final below. Every chunk is
-        # stacked, here, into one pooled buffer of k rows (the widest
-        # read set of any path) whose pages earlier jobs faulted in.
-        ranges = [(off, min(chunk, shard_size - off))
-                  for off in range(0, shard_size, chunk)]
+        # stacked, here, into one pooled buffer of k rows a substripe
+        # (the widest read set of any path) whose pages earlier jobs
+        # faulted in. A sub-striped code's chunk is a column range of
+        # every half: its rebuilt halves land at their own offsets.
+        ranges = [(off, min(chunk, part - off))
+                  for off in range(0, part, chunk)]
+        targets = missing if subs == 1 else \
+            [code.halfrow(s, h) for s in missing for h in range(subs)]
+        input_bytes: dict[str, int] = {}
         written = 0
         files = {s: open(base + geo.shard_ext(s), "wb")
                  for s in missing}
         try:
-            with staging_buffer(k * ranges[0][1]) as stage, \
+            with staging_buffer(k * subs * ranges[0][1]) as stage, \
                     ThreadPoolExecutor(
                         1, thread_name_prefix="ec-rebuild-gather") as ahead:
 
@@ -1933,15 +1990,25 @@ class VolumeServer:
                         rows = pending.result()
                     if i + 1 < len(ranges):
                         pending = gather_ahead(i + 1)
+                    for r, row in rows.items():
+                        sub = "whole" if subs == 1 else \
+                            "ab"[code.split_row(r)[1]]
+                        input_bytes[sub] = input_bytes.get(sub, 0) + \
+                            len(row)
                     with tracing.interval("ec.rebuild.reconstruct"):
-                        rec = rs.reconstruct(rows, missing=missing,
+                        rec = rs.reconstruct(rows, missing=targets,
                                              stage=stage)
                     with tracing.interval("ec.rebuild.write"):
-                        for s in missing:
-                            row = np.asarray(rec[s],
-                                             dtype=np.uint8).tobytes()
-                            files[s].write(row)
-                            written += len(row)
+                        if subs > 1:
+                            written += self._write_parts(
+                                code, files, rec, targets,
+                                ranges[i][0], part)
+                        else:
+                            for s in missing:
+                                row = np.asarray(
+                                    rec[s], dtype=np.uint8).tobytes()
+                                files[s].write(row)
+                                written += len(row)
         except Exception:
             for s, f in files.items():
                 f.close()
@@ -1961,8 +2028,32 @@ class VolumeServer:
         # above leave out: the two together are the plan's reads
         metrics.counter_add("ec_repair_local_read_bytes_total",
                             local_bytes, {"code": code.spec})
+        # the bytes of the ranges handed to the codec, local and remote
+        # (a half of a sub-striped code counts under its substripe)
+        for sub, nbytes in sorted(input_bytes.items()):
+            metrics.counter_add("ec_repair_input_bytes_total", nbytes,
+                                {"code": code.spec, "substripe": sub})
         return {"rebuilt_shards": missing, "rebuilt_bytes": written,
                 "read_bytes": net_bytes}
+
+    @staticmethod
+    def _write_parts(code, files: dict, rec: dict, targets: list,
+                     off: int, part: int) -> int:
+        """Positional writes of one chunk's rebuilt halves: half h of
+        shard s at h*part+off. The bytes written."""
+        import numpy as np
+
+        written = 0
+        for r in targets:
+            s, h = code.split_row(r)
+            view = memoryview(np.ascontiguousarray(rec[r],
+                                                   dtype=np.uint8))
+            pos = h * part + off
+            while len(view):
+                n = os.pwrite(files[s].fileno(), view, pos)
+                view, pos = view[n:], pos + n
+                written += n
+        return written
 
     async def handle_ec_copy(self, req: web.Request) -> web.Response:
         """VolumeEcShardsCopy (:126): pull shard files (and optionally
@@ -2429,7 +2520,19 @@ class VolumeServer:
                                   bps: float = 0.0) -> dict:
         """Concurrent first-k-wins shard-range fan-out for degraded
         reads and partial rebuilds (goroutine fan-out in
-        store_ec.go:349-393): every candidate shard is requested at
+        store_ec.go:349-393), every shard at the same `offset`:
+        {sid: bytes}. See _remote_ranges_fetch_sync."""
+        got = self._remote_ranges_fetch_sync(
+            vid, [(sid, offset) for sid in sids], size, need, deadline,
+            bps)
+        return {sid: data for (sid, _off), data in got.items()}
+
+    def _remote_ranges_fetch_sync(self, vid: int, ranges: list,
+                                  size: int, need: int,
+                                  deadline: float,
+                                  bps: float = 0.0) -> dict:
+        """The fan-out over (shard id, offset) ranges of `size` bytes:
+        {(sid, offset): bytes}. Every candidate range is requested at
         once, the pool holding a worker for each shard of the widest
         code (_FetchPool); the call returns as soon as `need` of them
         arrive or the deadline passes, so one hung peer costs nothing
@@ -2447,17 +2550,17 @@ class VolumeServer:
             pool = self.__dict__.setdefault("_ec_fetch_pool", _FetchPool())
         futs = {}
         queued = 0
-        for sid in sids:
+        for sid, offset in ranges:
             holders = [h for h in holders_map.get(str(sid), []) if h != me]
             if holders:
                 fut, waits = pool.submit_range(
                     self._fetch_shard_from_holders, vid, sid, holders,
                     offset, size, deadline_t, bps)
-                futs[fut] = sid
+                futs[fut] = (sid, offset)
                 queued += waits
         metrics.counter_add("ec_fetch_fanout_ranges_total", len(futs))
         metrics.counter_add("ec_fetch_fanout_queued_total", queued)
-        out: dict[int, bytes] = {}
+        out: dict[tuple[int, int], bytes] = {}
         pending = set(futs)
         with contextlib.ExitStack() as tail:
             while pending and len(out) < need:

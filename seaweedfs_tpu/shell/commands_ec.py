@@ -312,21 +312,41 @@ def ec_verify(env: CommandEnv, volume_id: int, sample_mb: int = 4,
         return {"volume": volume_id, "verified": False,
                 "missing_shards": missing}
     sample = sample_mb << 20
+    # a sub-striped code's shard is coded in parts (Hitchhiker's
+    # halves): the sample is the same prefix of every part, side by
+    # side, which is itself a codeword of the code
+    subs = code.substripes
+    part = 0
+    if subs > 1:
+        resp = session().get(f"http://{locs[0][0]}/admin/ec/shard_read",
+                            params={"volume": str(volume_id),
+                                    "shard": "0", "stat": "1"},
+                            timeout=60)
+        if resp.status_code != 200:
+            return {"volume": volume_id, "verified": False,
+                    "missing_shards": [0],
+                    "error": f"shard 0 stat: {resp.status_code}"}
+        part = int(resp.json()["size"]) // subs
+        sample = min(sample, part) if sample else part
     shards = []
     for sid in range(k + m):
         url = locs[sid][0]
-        params = {"volume": str(volume_id), "shard": str(sid),
-                  "offset": "0"}
-        if sample:
-            params["size"] = str(sample)
-        resp = session().get(f"http://{url}/admin/ec/shard_read",
-                            params=params, timeout=600)
-        if resp.status_code != 200:
-            return {"volume": volume_id, "verified": False,
-                    "missing_shards": [sid],
-                    "error": f"shard {sid} read from {url}: "
-                             f"{resp.status_code}"}
-        shards.append(np.frombuffer(resp.content, dtype=np.uint8))
+        pieces = []
+        for off in [h * part for h in range(subs)]:
+            params = {"volume": str(volume_id), "shard": str(sid),
+                      "offset": str(off)}
+            if sample:
+                params["size"] = str(sample)
+            resp = session().get(f"http://{url}/admin/ec/shard_read",
+                                params=params, timeout=600)
+            if resp.status_code != 200:
+                return {"volume": volume_id, "verified": False,
+                        "missing_shards": [sid],
+                        "error": f"shard {sid} read from {url}: "
+                                 f"{resp.status_code}"}
+            pieces.append(np.frombuffer(resp.content, dtype=np.uint8))
+        n_piece = min(len(p) for p in pieces)
+        shards.append(np.concatenate([p[:n_piece] for p in pieces]))
     n = min(len(s) for s in shards)
     stack = np.stack([s[:n] for s in shards])
     rs = ReedSolomon(k, m, backend=backend, code=code)
@@ -363,18 +383,24 @@ def _locate_corrupt_shard(rs, rows: dict) -> int | None:
     import numpy as np
 
     total = rs.k + rs.m
+    code = rs.code
+    subs = code.substripes
+    # the codec's rows: a shard each, or (sub-striped) a part each
+    parts = {code.halfrow(sid, h): piece for sid in rows
+             for h, piece in enumerate(np.split(rows[sid], subs))}
 
     def mismatches(basis: list[int]) -> list[int] | None:
+        want = [r for r in parts if code.split_row(r)[0] not in basis]
         try:
-            recon = rs.reconstruct({sid: rows[sid] for sid in basis},
-                                   missing=[i for i in range(total)
-                                            if i not in basis])
+            recon = rs.reconstruct(
+                {r: parts[r] for r in parts if r not in want},
+                missing=want)
         except ValueError:
             # dependent basis (possible for structured codes): this
             # basis can't decode — inconclusive, try the next
             return None
-        return [i for i in range(total) if i not in basis and
-                not np.array_equal(recon[i], rows[i])]
+        return sorted({code.split_row(r)[0] for r in want
+                       if not np.array_equal(recon[r], parts[r])})
 
     basis = list(range(rs.k))
     bad = mismatches(basis)

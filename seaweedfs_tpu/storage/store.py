@@ -493,6 +493,9 @@ class Store:
         in hand: structured codes carry dependent rows (an LRC local
         parity is the XOR of its group), so a first-k-by-count set can
         be rank-deficient while independent shards sit reachable."""
+        if ecv.code.substripes > 1:
+            return self._reconstruct_substriped(ecv, missing_sid, offset,
+                                                size)
         if not ecv.code.is_rs:
             data = self._reconstruct_planned(ecv, missing_sid, offset,
                                              size)
@@ -557,6 +560,85 @@ class Store:
         rec = self._rs_for(ecv, interval=True).reconstruct(
             rows, [missing_sid])
         return rec[missing_sid].tobytes()
+
+    def _reconstruct_substriped(self, ecv: EcVolume, missing_sid: int,
+                                offset: int, size: int) -> bytes:
+        """A lost shard's range of a sub-striped code (Hitchhiker),
+        split where its halves meet: each piece is one row of the code
+        (code.halfrow), rebuilt by _reconstruct_part."""
+        part = ecv.shard_size // ecv.code.substripes
+        out = []
+        end = offset + size
+        while offset < end:
+            h = offset // part
+            stop = min(end, (h + 1) * part)
+            out.append(self._reconstruct_part(ecv, missing_sid, h,
+                                              offset - h * part,
+                                              stop - offset, part))
+            offset = stop
+        return b"".join(out)
+
+    def _reconstruct_part(self, ecv: EcVolume, sid: int, half: int,
+                          x: int, size: int, part: int) -> bytes:
+        """Row (sid, half) of a sub-striped code at column x: gather
+        the other shards' same half first (an a-half is one RS
+        codeword of all shards; a b-half decodes from the data shards
+        and the first parity, whose b-half has no piggyback), then the
+        other half, which strips the piggybacks, until the target row
+        lies in the span of the rows read. Local rows first, the rest
+        over the first-need-wins fan-out."""
+        from ..ops import rs_matrix
+
+        code = ecv.code
+        target = code.halfrow(sid, half)
+        rows: dict[int, np.ndarray] = {}
+
+        def spans() -> bool:
+            return rs_matrix.in_span(code, list(rows), [target])
+
+        def grows(r: int) -> bool:
+            return rs_matrix.rank_of_rows(code, list(rows) + [r]) > \
+                len(rows)
+
+        for h in (half, 1 - half):
+            off = h * part + x
+            candidates = []
+            for s in range(ecv.total):
+                shard = ecv.shards.get(s)
+                if s == sid or code.halfrow(s, h) in rows:
+                    continue
+                if shard is None:
+                    candidates.append(s)
+                elif grows(code.halfrow(s, h)):
+                    rows[code.halfrow(s, h)] = np.frombuffer(
+                        shard.read_at(off, size), dtype=np.uint8)
+            reader = self.remote_shard_reader
+            while not spans() and candidates:
+                need = max(1, code.k - sum(code.split_row(r)[1] == h
+                                           for r in rows))
+                if self.remote_shards_fetcher is not None:
+                    got = self.remote_shards_fetcher(
+                        ecv.vid, candidates, off, size, need,
+                        self.ec_read_deadline)
+                    asked = set(got) or set(candidates)
+                else:  # legacy serial reader (tools, tests)
+                    asked = set(candidates[:need] if reader
+                                else candidates)
+                    got = {s: d for s in sorted(asked) if reader and
+                           (d := reader(ecv.vid, s, off, size))
+                           is not None}
+                for s in sorted(got):
+                    if grows(code.halfrow(s, h)):
+                        rows[code.halfrow(s, h)] = np.frombuffer(
+                            got[s], dtype=np.uint8)
+                candidates = [s for s in candidates if s not in asked]
+            if spans():
+                rec = self._rs_for(ecv, interval=True).reconstruct(
+                    rows, [target])
+                return rec[target].tobytes()
+        raise IOError(
+            f"cannot reconstruct shard {sid} of volume {ecv.vid}: "
+            f"{len(rows)} of its {code.spec} rows reachable")
 
     def _reconstruct_planned(self, ecv: EcVolume, missing_sid: int,
                              offset: int, size: int) -> bytes | None:

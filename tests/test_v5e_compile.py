@@ -58,17 +58,29 @@ def _parity(spec: str) -> np.ndarray:
     return rs_matrix.parity_rows_for(geo.parse_code(spec))
 
 
-@pytest.mark.parametrize("spec", ["10.4", "28.4", "lrc-12.3.2"])
-def test_pallas_kernel(one_chip, spec):
-    m, k = _parity(spec).shape
+def _compile_pallas(one_chip, m, k, cols):
     compiled = codec_pallas.coded_matmul_pallas_pm_donated.lower(
         _spec((8 * m, 8 * k), jnp.bfloat16, one_chip),
         _spec((m, 8 * m), jnp.bfloat16, one_chip),
-        _spec((k, PALLAS_COLS), jnp.uint8, one_chip)).compile()
+        _spec((k, cols), jnp.uint8, one_chip)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     # the op carries the program's own name, which a device trace shows
     assert f"%{codec_pallas.KERNEL_NAME}." in text
+
+
+# hh-10.4's encode is one (8, 20) coupled matmul over half-rows
+@pytest.mark.parametrize("spec", ["10.4", "28.4", "lrc-12.3.2", "hh-10.4"])
+def test_pallas_kernel(one_chip, spec):
+    m, k = _parity(spec).shape
+    _compile_pallas(one_chip, m, k, PALLAS_COLS)
+
+
+# hh-10.4's single data-shard rebuild: 2 rows out of 13 or 14 half
+# ranges, a 4 MiB chunk of each
+@pytest.mark.parametrize("fanin", [13, 14])
+def test_pallas_hitchhiker_reconstruct(one_chip, fanin):
+    _compile_pallas(one_chip, 2, fanin, 4 << 20)
 
 
 def test_xla_dense_kernel(one_chip):
